@@ -20,7 +20,9 @@ Two arms:
   not have met — the staged pipeline's memory bound is real, not
   nominal.  (Spill I/O uses ``np.save``/``tofile`` block reads, never
   mmap — mapped files would count against ``RLIMIT_AS`` and void the
-  proof.)
+  proof.)  The child is pinned to the CPU by its own environment
+  (``JAX_PLATFORMS=cpu``): the bound it proves is on host memory, and
+  an accelerator belongs to one process — the parent's.
 
 Writes ``BENCH_build.json`` at the repo root; the committed baseline is
 refreshed from ``--smoke`` so the weekly CI gate compares like against
@@ -155,6 +157,7 @@ def _run_oob(n: int, chunk_rows: int, headroom_mb: int,
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(repo_root, "src") + os.pathsep \
             + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"        # the chip stays the parent's
         proc = subprocess.run(
             [sys.executable, "-c", _OOB_CHILD, str(n), str(chunk_rows),
              str(headroom_mb), spill, out_path, str(seed)],
@@ -232,6 +235,8 @@ def bench_build():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = _parse()
     payload = run(args)
     for k, v in payload["results"].items():

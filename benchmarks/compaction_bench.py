@@ -187,6 +187,8 @@ def bench_compaction():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = _parse()
     payload = run(args)
     for k, v in payload["results"].items():

@@ -51,6 +51,8 @@ def _time(fn, reps):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     p = len(jax.devices())
     mesh = jax.make_mesh((p,), ("tablets",))
     codes = random_dna(ARGS.text_len, seed=0)

@@ -10,6 +10,8 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import build_bench, client_bench, compaction_bench, \
         fm_bench, kernel_bench, paper_tables, plane_bench, roofline, \
         serving_bench, table_bench, wal_bench

@@ -20,7 +20,11 @@ Three questions, one scripted load run (docs/observability.md):
   half lives in ``launch/run.sh`` and needs the .so present, so it is
   applied when available);  startup seconds and stderr log bytes are
   reported, plus ``tuned_not_noisier`` (the preset must never ADD log
-  noise — gated as a flag).
+  noise — gated as a flag).  The probes run before this process
+  touches JAX: an accelerator belongs to one process, and a child that
+  needs it after the parent has claimed it fails or hangs.  When the
+  parent already holds a backend (the ``benchmarks/run.py`` entry), the
+  children are pinned to the CPU.
 
 Writes ``BENCH_serving.json`` at the repo root; ``--feed-out PATH``
 additionally copies the load run's feed for ``--from-feed`` gating.
@@ -181,9 +185,12 @@ _STARTUP_CODE = (
 
 def _startup(env_extra: dict) -> tuple[float, int]:
     """(import+first-dispatch seconds, stderr bytes) in a fresh child."""
+    from jax._src import xla_bridge
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env.update(env_extra)
+    if xla_bridge.backends_are_initialized():
+        env["JAX_PLATFORMS"] = "cpu"        # the chip is this process's
     proc = subprocess.run([sys.executable, "-c", _STARTUP_CODE],
                           capture_output=True, text=True, env=env,
                           timeout=300)
@@ -215,6 +222,7 @@ def _tuned_effect() -> dict:
 
 
 def run(args) -> dict:
+    tuned = _tuned_effect()                 # before this process uses JAX
     from repro.api import Database, SuffixTable
     from repro.core.codec import random_dna
 
@@ -237,7 +245,6 @@ def run(args) -> dict:
     finally:
         db.close()
         shutil.rmtree(tmp, ignore_errors=True)
-    tuned = _tuned_effect()
     return {
         "bench": "serving_observability",
         "text_len": args.text_len,
@@ -265,6 +272,8 @@ def bench_serving():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = _parse()
     payload = run(args)
 

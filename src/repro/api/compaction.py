@@ -136,7 +136,7 @@ def merge_delta_sa(combined: np.ndarray, n0: int, base_sa_real: np.ndarray,
 
     if is_dna:
         W = codec.packed_length(L)
-        packed = np.asarray(codec.pack_2bit(combined))
+        packed = codec.pack_2bit(combined)
         packed = np.concatenate(
             [packed, np.zeros(_pow2(packed.shape[0]) - packed.shape[0],
                               np.uint32)])

@@ -39,11 +39,12 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import codec
+from repro.core.rangemin import block_minima
 from repro.core.suffix_array import build_suffix_array
 from repro.kernels import fm_scan
 from repro.kernels.fm_scan import SB, WPB, FMArrays
 
-FM_FORMAT = 1
+FM_FORMAT = 2
 DEFAULT_SAMPLE_RATE = 32
 MAX_VOCAB = 64          # token tables above this stay on the live tier
 
@@ -88,14 +89,15 @@ class FMIndex:
     the device view (``.arrays``) is materialized lazily."""
 
     def __init__(self, *, bwt, occ, cc, marked, marked_rank, samples,
-                 sent_row: int, n: int, is_dna: bool, sample_rate: int,
-                 vocab: int):
+                 row_min, sent_row: int, n: int, is_dna: bool,
+                 sample_rate: int, vocab: int):
         self.bwt = bwt                    # DNA: (Wb,) u32 | tokens: (L,) u8
         self.occ = occ                    # (nblk + 1, vocab) int32
         self.cc = cc                      # (vocab,) int32
         self.marked = marked              # (Wm,) uint32
         self.marked_rank = marked_rank    # (Wm,) int32
         self.samples = samples            # (S,) int32
+        self.row_min = row_min            # (rows / BLOCK,) SA$ block minima
         self.sent_row = int(sent_row)
         self.n = int(n)
         self.is_dna = bool(is_dna)
@@ -178,9 +180,10 @@ class FMIndex:
         marked_rank = np.concatenate(
             ([0], np.cumsum(per_word)[:-1])).astype(np.int32)
         samples = sa_dollar[mark].astype(np.int32)
+        row_min = block_minima(sa_dollar).astype(np.int32)
 
         return cls(bwt=bwt_store, occ=occ, cc=cc, marked=marked,
-                   marked_rank=marked_rank, samples=samples,
+                   marked_rank=marked_rank, samples=samples, row_min=row_min,
                    sent_row=sent_row, n=n, is_dna=is_dna,
                    sample_rate=sample_rate, vocab=vocab)
 
@@ -287,7 +290,8 @@ class FMIndex:
     def state_dict(self) -> dict:
         return {"bwt": np.asarray(self.bwt), "occ": self.occ,
                 "cc": self.cc, "marked": self.marked,
-                "marked_rank": self.marked_rank, "samples": self.samples}
+                "marked_rank": self.marked_rank, "samples": self.samples,
+                "row_min": self.row_min}
 
     def extra_dict(self) -> dict:
         return {"kind": "fm_index", "format": FM_FORMAT, "n": self.n,
@@ -320,6 +324,7 @@ class FMIndex:
                    marked=a["marked"].astype(np.uint32),
                    marked_rank=a["marked_rank"].astype(np.int32),
                    samples=a["samples"].astype(np.int32),
+                   row_min=a["row_min"].astype(np.int32),
                    sent_row=int(extra["sent_row"]), n=int(extra["n"]),
                    is_dna=is_dna, sample_rate=int(extra["sample_rate"]),
                    vocab=int(extra["vocab"]))
@@ -329,4 +334,5 @@ class FMIndex:
         """Index bytes (host copy == device copy sizes)."""
         return int(np.asarray(self.bwt).nbytes + self.occ.nbytes
                    + self.cc.nbytes + self.marked.nbytes
-                   + self.marked_rank.nbytes + self.samples.nbytes)
+                   + self.marked_rank.nbytes + self.samples.nbytes
+                   + self.row_min.nbytes)

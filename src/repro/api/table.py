@@ -58,11 +58,12 @@ from repro.core.build_pipeline import BuildStats, chunk_rows_for_budget, \
     in_memory_build_stats, staged_suffix_array
 from repro.core.planner import ScanOutcome, ScanPlanner, TopKCache
 from repro.core.query import MatchResult
+from repro.core.rangemin import range_min, range_smallest
 from repro.serving.metrics import MetricsEmitter, table_record
 from repro.serving.trace import Tracer
 from repro.core.suffix_array import build_suffix_array
 from repro.core.tablet import TabletStore, build_tablet_store, \
-    store_from_arrays
+    place_on_mesh, store_from_arrays
 from repro.launch.mesh import make_tablet_mesh
 
 # no leading dot: forbids '.', '..' (path traversal — drop_table rmtree's
@@ -425,6 +426,8 @@ class SuffixTable:
         self.store = store_from_arrays(
             codes, sa_real, is_dna=self.is_dna,
             max_query_len=self.max_query_len, num_tablets=p)
+        if self.mesh is not None:
+            self.store = place_on_mesh(self.store, self.mesh)
         planner = getattr(self, "planner", None)
         if planner is None:
             self.planner = ScanPlanner(
@@ -665,28 +668,25 @@ class SuffixTable:
             tres.count)[:, :B].astype(np.int64).sum(axis=0)
         return merged, tres, delta, base_count
 
+    def _base_rows(self):
+        """(row -> text position getter, its block minima, row offset of
+        real-SA rank 0) for the base tier — the host SA mirror, or on a
+        frozen table LF walks over ``SA$`` (real rank r is row r + 1)."""
+        if self.fm is not None:
+            return self.fm.ranks_to_positions, self.fm.row_min, 1
+        sa = self._sa()
+        return sa.__getitem__, self.planner._sa_block_min(), \
+            self.store.pad_count
+
     def _base_min_positions(self, base_count, base_rank) -> np.ndarray:
         """Per query, the smallest BASE text position among its base-tier
-        matches (-1 when none): one vectorized flat gather + segmented
-        min over the SA slices ``[lb, lb + count)`` — the text-order
-        ``first_pos`` reduction, with no per-query dispatch."""
-        B = int(base_count.shape[0])
-        out = np.full(B, -1, np.int64)
-        nz = np.flatnonzero((base_count > 0) & (base_rank >= 0))
-        if nz.size == 0:
-            return out
-        cnt = base_count[nz].astype(np.int64)
-        starts = self.store.pad_count + base_rank[nz].astype(np.int64)
-        seg = np.concatenate([[0], np.cumsum(cnt)[:-1]])
-        flat = np.repeat(starts - seg, cnt) + np.arange(int(cnt.sum()))
-        if self.fm is not None:
-            # frozen tier: no SA to gather — LF-walk the SA$ rows
-            # (real-SA row r is SA$ row r + 1) back to text positions
-            vals = self.fm.ranks_to_positions(flat + 1)
-        else:
-            vals = self._sa()[flat]
-        out[nz] = np.minimum.reduceat(vals.astype(np.int64), seg)
-        return out
+        matches (-1 when none) — the text-order ``first_pos`` reduction
+        over the rows ``[lb, lb + count)``, bounded by block minima
+        (``core.rangemin``) instead of gathering every match."""
+        get, bmin, off = self._base_rows()
+        count = np.where(base_rank >= 0, base_count, 0)
+        return range_min(get, bmin, off + base_rank.astype(np.int64),
+                         count)
 
     def scan_encoded(self, patt, plen, *, mode: Optional[str] = None
                      ) -> MatchResult:
@@ -703,6 +703,15 @@ class SuffixTable:
         merged, _tres = self.planner.scan_tiers(self._tierset(), patt,
                                                 plen, mode=mode)
         return merged
+
+    def _base_smallest(self, base_count, base_rank, i, k) -> np.ndarray:
+        """The ``k`` smallest base-tier text positions of row ``i``'s
+        matches, ascending."""
+        if base_rank[i] < 0:
+            return np.zeros((0,), np.int64)
+        get, bmin, off = self._base_rows()
+        return range_smallest(get, bmin, off + int(base_rank[i]),
+                              int(base_count[i]), k)
 
     def _base_slice(self, base_count, base_rank, i) -> np.ndarray:
         """Base-tier SA slice of row ``i``'s matches (text positions,
@@ -763,7 +772,8 @@ class SuffixTable:
                 if g.size and (first_pos[i] < 0 or g[0] < first_pos[i]):
                     first_pos[i] = int(g[0])
                 if top_k:
-                    run = self._base_slice(base_count, base_rank, i)
+                    run = self._base_smallest(base_count, base_rank, i,
+                                              top_k)
                     cand = np.concatenate([run, g])
                     if cand.size > top_k:
                         cand = np.partition(cand, top_k - 1)[:top_k]

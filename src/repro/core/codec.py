@@ -50,19 +50,13 @@ def packed_length(n_bases: int) -> int:
     return (n_bases + BASES_PER_WORD - 1) // BASES_PER_WORD
 
 
-def pack_2bit(codes) -> jnp.ndarray:
+def pack_2bit(codes) -> np.ndarray:
     """uint8 codes {0..3} -> uint32 words, big-endian: base i of word w sits at
     bit 30-2*i.  Trailing slots are zero-padded (== 'A'; harmless because all
-    compares are depth-capped by the caller)."""
-    codes = jnp.asarray(codes, dtype=jnp.uint32)
-    n = codes.shape[0]
-    n_words = packed_length(n)
-    pad = n_words * BASES_PER_WORD - n
-    codes = jnp.pad(codes, (0, pad))
-    lanes = codes.reshape(n_words, BASES_PER_WORD)
-    shifts = jnp.arange(BASES_PER_WORD, dtype=jnp.uint32)
-    shifts = (30 - 2 * shifts).astype(jnp.uint32)
-    return jnp.bitwise_or.reduce(lanes << shifts[None, :], axis=1)
+    compares are depth-capped by the caller).  Host numpy words: on a TPU
+    the ``(n/16, 16)`` lane layout of a device pack pads to 128 lanes, about
+    36 bytes of temporary HBM per base at genome scale."""
+    return pack_2bit_batch(np.asarray(codes).reshape(1, -1))[0]
 
 
 def pack_2bit_batch(codes: np.ndarray) -> np.ndarray:

@@ -23,7 +23,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.dsort import (bitonic_sort_sharded, sample_sort_sharded,
                               sort_sharded_auto)
 from repro.distributed.sharding import mesh_axis_size
@@ -124,7 +123,7 @@ def make_superchunk_sorter(mesh, axis_name: str, method: str = "sample"):
     spec = P(axis_name)
 
     @jax.jit
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(spec,) * 3,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,) * 3,
                        out_specs=(spec,) * 3)
     def run(key, nxt, idx):
         return _sort((key, nxt, idx), 3, axis_name, method)
@@ -147,7 +146,7 @@ def build_suffix_array_distributed(codes: np.ndarray, mesh, axis_name: str,
     fn = functools.partial(build_suffix_array_sharded, n_real=n_real,
                            axis_name=axis_name, method=method)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(spec,),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec,),
                        out_specs=(spec, spec))
     def run(c):
         return fn(c)

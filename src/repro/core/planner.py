@@ -52,11 +52,12 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.core import codec
 from repro.core import query as Q
 from repro.core.query import MatchResult
+from repro.core.rangemin import block_minima
 from repro.core.tablet import TabletStore
 from repro.serving.trace import Tracer
 
@@ -235,6 +236,9 @@ class ScanOutcome:
     positions: Optional[np.ndarray] = None   # (B, top_k) int64 | None
 
 
+_query_single = jax.jit(Q.query)
+
+
 class ScanPlanner:
     """Plans, executes, retries, and caches pattern scans over a store.
 
@@ -285,6 +289,7 @@ class ScanPlanner:
         self.tracer = tracer if tracer is not None else Tracer()
         self._cache = TopKCache(self.cache_size)
         self._sa_host: Optional[np.ndarray] = None
+        self._sa_bmin: Optional[np.ndarray] = None
         # executors are built lazily and injectable for tests: each maps
         # (patt, plen) -> MatchResult
         self._executors: dict[str, Callable] = {}
@@ -315,6 +320,7 @@ class ScanPlanner:
         self.max_pattern_len = int(store.max_query_len)
         self._executors.clear()
         self._sa_host = None
+        self._sa_bmin = None
         self._cache.bump()
 
     def invalidate_cache(self) -> int:
@@ -359,7 +365,10 @@ class ScanPlanner:
     def _build_executor(self, mode: str) -> Callable:
         store = self.store
         if mode == MODE_SINGLE:
-            return jax.jit(lambda patt, plen: Q.query(store, patt, plen))
+            # the store is an ARGUMENT, not a closure: a jit closure over
+            # device arrays embeds them in the program as constants (the
+            # whole SA and text at genome scale)
+            return lambda patt, plen: _query_single(store, patt, plen)
         if mode == MODE_FM:
             if self.fm is None:
                 raise ValueError("mode 'fm' requires a frozen table "
@@ -370,21 +379,24 @@ class ScanPlanner:
 
         from jax.sharding import PartitionSpec as P
         ax = self.axis_name
+        # the sharded scans read the text through ``meta`` (replicated,
+        # an argument — see MODE_SINGLE) and their own tablet of the SA
+        meta = dataclasses.replace(store, sa=jnp.zeros((0,), jnp.int32))
         if mode == MODE_BROADCAST:
             @jax.jit
             @partial(shard_map, mesh=self.mesh,
-                     in_specs=(P(ax), None, P(), P()), out_specs=P())
+                     in_specs=(P(ax), P(), P(), P()), out_specs=P())
             def broadcast(sa_local, meta, patt, plen):
                 return Q.query_sharded(sa_local, meta, patt, plen, ax)
 
-            return lambda patt, plen: broadcast(store.sa, store, patt, plen)
+            return lambda patt, plen: broadcast(store.sa, meta, patt, plen)
 
         if mode == MODE_ROUTED:
             cf = self.capacity_factor
 
             @jax.jit
             @partial(shard_map, mesh=self.mesh,
-                     in_specs=(P(ax), None, P(ax), P(ax)), out_specs=P(ax))
+                     in_specs=(P(ax), P(), P(ax), P(ax)), out_specs=P(ax))
             def routed(sa_local, meta, patt, plen):
                 return Q.query_routed(sa_local, meta, patt, plen, ax,
                                       capacity_factor=cf)
@@ -400,12 +412,16 @@ class ScanPlanner:
                                          patt.dtype)])
                     plen = jnp.concatenate(
                         [plen, jnp.ones((pad,), plen.dtype)])
-                res = routed(store.sa, store, patt, plen)
+                res = routed(store.sa, meta, patt, plen)
                 if pad:
-                    res = MatchResult(found=res.found[:B],
-                                      count=res.count[:B],
-                                      first_rank=res.first_rank[:B],
-                                      first_pos=res.first_pos[:B])
+                    # trimmed on the host: the outputs are split over an
+                    # Explicit mesh axis, where a device slice to B rows
+                    # (not a multiple of p) has no unambiguous sharding
+                    res = MatchResult(
+                        found=np.asarray(res.found)[:B],
+                        count=np.asarray(res.count)[:B],
+                        first_rank=np.asarray(res.first_rank)[:B],
+                        first_pos=np.asarray(res.first_pos)[:B])
                 return res
 
             return run
@@ -583,6 +599,12 @@ class ScanPlanner:
         if self._sa_host is None:
             self._sa_host = np.asarray(self.store.sa)
         return self._sa_host
+
+    def _sa_block_min(self) -> np.ndarray:
+        """Block minima of the host SA mirror (``core.rangemin``)."""
+        if self._sa_bmin is None:
+            self._sa_bmin = block_minima(self._sa())
+        return self._sa_bmin
 
     def locate_encoded(self, patt, plen, top_k: int = 8,
                        *, mode: Optional[str] = None) -> np.ndarray:

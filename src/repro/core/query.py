@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import pcast_varying
 from repro.core import codec
 from repro.core.tablet import TabletStore
 
@@ -195,8 +194,8 @@ def _bounded_search(sa: jnp.ndarray, pred_fn, batch: int, n_rows: int,
     lo = jnp.zeros((batch,), jnp.int32)
     hi = jnp.full((batch,), n_rows, jnp.int32)
     if varying_axis is not None:
-        lo = pcast_varying(lo, varying_axis)
-        hi = pcast_varying(hi, varying_axis)
+        lo = lax.pcast(lo, varying_axis, to="varying")
+        hi = lax.pcast(hi, varying_axis, to="varying")
     lo, _ = lax.fori_loop(0, steps, body, (lo, hi))
     return lo
 

@@ -195,7 +195,7 @@ def stack_tier_stores(stores, *, offsets, bounds) -> TierStack:
 def _finalize_store(codes: np.ndarray, sa, n_pad: int, *, is_dna: bool,
                     max_query_len: int) -> TabletStore:
     n_real = int(codes.shape[0])
-    text_packed = codec.pack_2bit(codes) if is_dna else None
+    text_packed = jnp.asarray(codec.pack_2bit(codes)) if is_dna else None
     # generic code array padded with -1 so out-of-range gathers sort low
     text_codes = jnp.asarray(
         np.pad(codes.astype(np.int32), (0, n_pad - n_real),
@@ -237,6 +237,22 @@ def store_from_arrays(codes, sa_real, *, is_dna: bool,
     sa = jnp.asarray(np.concatenate([pads, sa_real]))
     return _finalize_store(codes, sa, n_pad, is_dna=bool(is_dna),
                            max_query_len=max_query_len)
+
+
+def place_on_mesh(store: TabletStore, mesh,
+                  axis_name: str = "tablets") -> TabletStore:
+    """Commit a store to a tablet mesh: SA rows split into one
+    contiguous tablet per device, text replicated on every device — the
+    layouts the sharded scans read (``core/planner.py``), so a batch
+    moves only its patterns, never the index."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rows = NamedSharding(mesh, P(axis_name))
+    everywhere = NamedSharding(mesh, P())
+    text = {k: jax.device_put(v, everywhere)
+            for k, v in (("text_packed", store.text_packed),
+                         ("text_codes", store.text_codes)) if v is not None}
+    return dataclasses.replace(store, sa=jax.device_put(store.sa, rows),
+                               **text)
 
 
 def build_tablet_store(codes, *, is_dna: bool | None = None,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import pcast_varying
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, axis_name):
     """Run ``stage_fn(params, h) -> h`` over p pipeline stages.
@@ -62,8 +60,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, axis_name):
 
     outs0 = jnp.zeros((n_micro,) + mb_shape, x_micro.dtype)
     recv0 = jnp.zeros(mb_shape, x_micro.dtype)
-    recv0 = pcast_varying(recv0, axis_name)
-    outs0 = pcast_varying(outs0, axis_name)
+    recv0 = lax.pcast(recv0, axis_name, to="varying")
+    outs0 = lax.pcast(outs0, axis_name, to="varying")
     (_, outs), _ = lax.scan(tick, (recv0, outs0), jnp.arange(ticks))
     # broadcast final outputs from the last stage to all stages (masked
     # psum — ppermute can't fan out one source to many destinations)

@@ -20,24 +20,22 @@ Index layout (built host-side by ``repro.api.fm.FMIndex``):
 * tokens: uint8 BWT, per-symbol Occ checkpoints, compare-equal sums.
 
 Per-step pattern symbols are pre-extracted into a dense ``(steps, B)``
-plan (-1 = step inactive for that query), so the jnp oracle and the
-Pallas kernel execute the identical schedule: the kernel's inner loop is
-checkpoint gathers + popcounts, no per-query pattern indexing.
+plan (-1 = step inactive for that query), so the search loop is
+checkpoint gathers + popcounts, no per-query pattern indexing.  The
+search runs as XLA on every backend: the index stays in HBM and each
+step gathers only the checkpoints and words it needs.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
 
 SB = 64                 # symbols per Occ checkpoint block
 WPB = SB // 16          # packed words per block (DNA)
-BLOCK_Q = 128           # queries per Pallas program
 _EVEN = 0x55555555      # every 2-bit slot's low bit
 
 
@@ -106,8 +104,7 @@ def _rank_codes(bwt, occ_flat, sent_row, vocab, c, i):
 
 def rank(fa: FMArrays, c, i):
     """Occ(c, i) over the index — the rank primitive shared by backward
-    search and LF walks (jnp oracle; the Pallas kernel inlines the
-    packed variant)."""
+    search and LF walks."""
     occ_flat = fa.occ.reshape(-1)
     if fa.is_dna:
         return _rank_packed(fa.bwt, occ_flat, fa.sent_row, c, i)
@@ -143,7 +140,7 @@ def syms_from_codes(patt: jnp.ndarray, plen: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# backward search — jnp oracle (and the non-DNA production path)
+# backward search
 # ---------------------------------------------------------------------------
 def search_syms(fa: FMArrays, syms: jnp.ndarray):
     """Backward search over a (steps, B) symbol plan -> (lo, hi) int32
@@ -222,76 +219,6 @@ def lf_walk(fa: FMArrays, rows):
             jnp.zeros(r.shape, bool))
     _, _, pos, _ = lax.fori_loop(0, fa.sample_rate + 1, body, init)
     return pos
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel (packed DNA): the backward search as a blocked launch
-# ---------------------------------------------------------------------------
-def _fm_kernel(syms_ref, bwt_ref, occ_ref, meta_ref, lo_ref, hi_ref,
-               *, steps: int):
-    syms = syms_ref[...]                    # (steps, BLOCK_Q) int32
-    bwt = bwt_ref[0]                        # (Wb,) uint32
-    occ_flat = occ_ref[...].reshape(-1)     # (nblk1 * 4,) int32
-    meta = meta_ref[0]                      # (8,) int32
-    cc = meta[:4]
-    sent = meta[4]
-    rows = meta[5]
-    B = syms.shape[1]
-    lo0 = jnp.zeros((B,), jnp.int32)
-    hi0 = jnp.full((B,), 1, jnp.int32) * rows
-
-    def body(t, carry):
-        lo, hi = carry
-        s = lax.dynamic_slice_in_dim(syms, t, 1, axis=0)[0]
-        active = s >= 0
-        sc = jnp.clip(s, 0, 3)
-        lo2 = jnp.take(cc, sc) + _rank_packed(bwt, occ_flat, sent, sc, lo)
-        hi2 = jnp.take(cc, sc) + _rank_packed(bwt, occ_flat, sent, sc, hi)
-        lo = jnp.where(active, lo2, lo)
-        hi = jnp.where(active, hi2, hi)
-        return lo, hi
-
-    lo, hi = lax.fori_loop(0, steps, body, (lo0, hi0))
-    lo_ref[0, :] = lo
-    hi_ref[0, :] = hi
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fm_scan_pallas(syms: jnp.ndarray, bwt: jnp.ndarray, occ: jnp.ndarray,
-                   meta: jnp.ndarray, *, interpret: bool = False):
-    """syms: (steps, BQtot) int32 backward-order symbol plan (-1 =
-    inactive; BQtot % BLOCK_Q == 0 — caller pads); bwt: (Wb,) uint32
-    packed BWT; occ: (nblk + 1, 4) int32 checkpoints; meta: (8,) int32
-    ``[C0..C3, sent_row, rows, 0, 0]``.  Returns (lo, hi) int32
-    (BQtot,).  The whole index stays resident across the query grid —
-    at 64 symbols/checkpoint a 1 Mbase BWT is ~0.6 MB."""
-    steps, BQ = syms.shape
-    assert BQ % BLOCK_Q == 0
-    grid = (BQ // BLOCK_Q,)
-    kernel = functools.partial(_fm_kernel, steps=steps)
-    lo, hi = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((steps, BLOCK_Q), lambda q: (0, q)),
-            pl.BlockSpec((1, bwt.shape[0]), lambda q: (0, 0)),
-            pl.BlockSpec(occ.shape, lambda q: (0, 0)),
-            pl.BlockSpec((1, 8), lambda q: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, BLOCK_Q), lambda q: (0, q))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((1, BQ), jnp.int32)] * 2,
-        interpret=interpret,
-    )(syms, bwt[None, :], occ, meta[None, :])
-    return lo[0], hi[0]
-
-
-def pallas_meta(fa: FMArrays) -> jnp.ndarray:
-    """The (8,) int32 scalar block ``fm_scan_pallas`` wants."""
-    meta = jnp.zeros((8,), jnp.int32)
-    meta = meta.at[:4].set(fa.cc.astype(jnp.int32))
-    meta = meta.at[4].set(fa.sent_row.astype(jnp.int32))
-    meta = meta.at[5].set(fa.n.astype(jnp.int32) + 1)
-    return meta
 
 
 def finish_match(fa: FMArrays, lo, hi):

@@ -1,6 +1,6 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True``; on TPU
+On CPU the kernels execute via ``interpret=True``; on TPU
 they compile to Mosaic.  Wrappers handle padding to kernel block multiples
 and layout transposition so callers keep natural (B, W) shapes.
 """
@@ -86,7 +86,7 @@ def tier_scan(stack, patterns, plen):
     wt, _ = _pad_to(jnp.transpose(windows, (0, 2, 1)).astype(jnp.uint32),
                     _tier.BLOCK_R, 2)
     sa_p, _ = _pad_to(stack.sa.astype(jnp.int32), _tier.BLOCK_R, 1)
-    pt, B = _pad_to(patterns.T.astype(jnp.uint32), _tier.BLOCK_Q, 1)
+    pt, B = _pad_to(patterns.astype(jnp.uint32), _tier.BLOCK_Q, 0)
     pl_, _ = _pad_to(plen.astype(jnp.int32), _tier.BLOCK_Q, 0, fill=1)
     meta = jnp.zeros((T, 8), jnp.int32)
     meta = meta.at[:, 0].set(stack.n_real.astype(jnp.int32))
@@ -142,21 +142,13 @@ def fm_search(arrays, patterns, plen):
     ``query`` with one widening: ``first_rank`` is the real-SA lower
     bound for EVERY query (found or not) — ``merge_tier_results`` only
     reads it through a ``count > 0`` guard, so the paths stay
-    bit-identical where it matters.  Packed-DNA batches take the Pallas
-    kernel on TPU; everything else runs the jnp oracle."""
+    bit-identical where it matters.  The search is XLA on every backend
+    (the index stays in HBM; a chip kernel is ROADMAP S5)."""
     if arrays.is_dna and patterns.dtype == jnp.uint32:
         syms = _fm.syms_from_packed(patterns, plen, patterns.shape[1] * 16)
     else:
         syms = _fm.syms_from_codes(patterns, plen, patterns.shape[1])
-    if (not _interpret()) and arrays.is_dna \
-            and patterns.dtype == jnp.uint32:
-        padded, B = _pad_to(syms, _fm.BLOCK_Q, 1, fill=-1)
-        lo, hi = _fm.fm_scan_pallas(padded, arrays.bwt, arrays.occ,
-                                    _fm.pallas_meta(arrays),
-                                    interpret=False)
-        lo, hi = lo[:B], hi[:B]
-    else:
-        lo, hi = _fm.search_syms(arrays, syms)
+    lo, hi = _fm.search_syms(arrays, syms)
     found, count, first_rank, first_pos = _fm.finish_match(arrays, lo, hi)
     return _q.MatchResult(found=found, count=count,
                           first_rank=first_rank, first_pos=first_pos)

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import codec
 from repro.core import query as Q
@@ -275,24 +276,25 @@ def merge_tier_results(base, tier_count, tier_first):
 # ---------------------------------------------------------------------------
 # Pallas kernel: dense blocked scan with a tier grid axis (DNA-packed)
 # ---------------------------------------------------------------------------
-def _tier_kernel(patt_ref, plen_ref, win_ref, sa_ref, meta_ref,
+def _tier_kernel(meta_ref, patt_ref, plen_ref, win_ref, sa_ref,
                  count_ref, less_ref, match_ref, first_ref,
                  *, n_words: int):
-    plen = plen_ref[...].reshape(-1, 1).astype(jnp.int32)   # (BQ, 1)
-    salocal = sa_ref[0, 0, :].reshape(1, -1)                # (1, BR)
-    n_real = meta_ref[0, 0]
-    n_rows = meta_ref[0, 1]
-    offset = meta_ref[0, 2]
-    lo_b = meta_ref[0, 3]
-    hi_b = meta_ref[0, 4]
+    t = pl.program_id(0)
+    plen = plen_ref[...]                                    # (BQ, 1)
+    salocal = sa_ref[0]                                     # (1, BR)
+    n_real = meta_ref[t, 0]                                 # SMEM scalars
+    n_rows = meta_ref[t, 1]
+    offset = meta_ref[t, 2]
+    lo_b = meta_ref[t, 3]
+    hi_b = meta_ref[t, 4]
 
     bq = plen.shape[0]
     br = salocal.shape[1]
     pe = jnp.ones((bq, br), jnp.bool_)
     lt = jnp.zeros((bq, br), jnp.bool_)
     for w in range(n_words):
-        a = win_ref[0, w, :][None, :]                       # row word (1,BR)
-        b = patt_ref[w, :][:, None]                         # pattern  (BQ,1)
+        a = win_ref[0, w:w + 1, :]                          # row word (1,BR)
+        b = patt_ref[:, w:w + 1]                            # pattern  (BQ,1)
         r = jnp.clip(plen - w * 16, 0, 16).astype(jnp.uint32)
         full = jnp.uint32(0xFFFFFFFF)
         mask = jnp.where(r == 0, jnp.uint32(0),
@@ -314,55 +316,66 @@ def _tier_kernel(patt_ref, plen_ref, win_ref, sa_ref, meta_ref,
     g = salocal + offset                                    # global starts
     e = g + plen
     owned = eq & (e > lo_b) & (e <= hi_b)                   # straddle rule
-    first = jnp.min(jnp.where(owned, g, jnp.int32(BIG)), axis=1)   # (BQ,)
-    cnt = jnp.sum(owned.astype(jnp.int32), axis=1)
-    mat = jnp.sum(eq.astype(jnp.int32), axis=1)
-    less = jnp.sum(lt.astype(jnp.int32), axis=1)
+    # keepdims: the per-query results stay (BQ, 1) columns, the layout
+    # the compare tile reduces into — no lane/sublane relayout
+    first = jnp.min(jnp.where(owned, g, jnp.int32(BIG)), axis=1,
+                    keepdims=True)
+    cnt = jnp.sum(owned.astype(jnp.int32), axis=1, keepdims=True)
+    mat = jnp.sum(eq.astype(jnp.int32), axis=1, keepdims=True)
+    less = jnp.sum(lt.astype(jnp.int32), axis=1, keepdims=True)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        count_ref[...] = cnt[None, :]
-        less_ref[...] = less[None, :]
-        match_ref[...] = mat[None, :]
-        first_ref[...] = first[None, :]
+        count_ref[0] = cnt
+        less_ref[0] = less
+        match_ref[0] = mat
+        first_ref[0] = first
 
     @pl.when(pl.program_id(2) != 0)
     def _acc():
-        count_ref[...] += cnt[None, :]
-        less_ref[...] += less[None, :]
-        match_ref[...] += mat[None, :]
-        first_ref[...] = jnp.minimum(first_ref[...], first[None, :])
+        count_ref[0] += cnt
+        less_ref[0] += less
+        match_ref[0] += mat
+        first_ref[0] = jnp.minimum(first_ref[0], first)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def tier_scan_pallas(patterns_t: jnp.ndarray, plen: jnp.ndarray,
+def tier_scan_pallas(patterns: jnp.ndarray, plen: jnp.ndarray,
                      windows_t: jnp.ndarray, sa: jnp.ndarray,
                      meta: jnp.ndarray, *, interpret: bool = False):
-    """patterns_t: (W, BQtot) uint32; plen: (BQtot,) int32; windows_t:
+    """patterns: (BQtot, W) uint32; plen: (BQtot,) int32; windows_t:
     (T, W, BRtot) uint32 — packed windows of every tier's stacked sorted
     rows; sa: (T, BRtot) int32 LOCAL text positions of those rows; meta:
     (T, 8) int32 rows of ``[n_real, n_rows, offset, lo, hi, 0, 0, 0]``
-    per tier.  BQtot % BLOCK_Q == 0 and BRtot % BLOCK_R == 0 (caller
-    pads; rows past ``n_rows`` are masked).  Returns (count, less,
-    matches, first_g) int32 (T, BQtot)."""
+    per tier, scalar-prefetched into SMEM.  BQtot % BLOCK_Q == 0 and
+    BRtot % BLOCK_R == 0 (caller pads; rows past ``n_rows`` are masked).
+    Returns (count, less, matches, first_g) int32 (T, BQtot).
+
+    Every block's last two dimensions are (8, 128)-divisible or span
+    the whole array dimension, so the layout tiles at any tier count T;
+    the outputs are (T, BQtot, 1) columns inside the kernel."""
     T, W, BR = windows_t.shape
-    BQ = patterns_t.shape[1]
+    BQ = patterns.shape[0]
     assert BQ % BLOCK_Q == 0 and BR % BLOCK_R == 0
     grid = (T, BQ // BLOCK_Q, BR // BLOCK_R)
     kernel = functools.partial(_tier_kernel, n_words=W)
-    qvec = pl.BlockSpec((1, BLOCK_Q), lambda t, q, r: (t, q))
+    qcol = pl.BlockSpec((1, BLOCK_Q, 1), lambda t, q, r, meta: (t, q, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((W, BLOCK_Q), lambda t, q, r: (0, q)),
-            pl.BlockSpec((1, BLOCK_Q), lambda t, q, r: (0, q)),
-            pl.BlockSpec((1, W, BLOCK_R), lambda t, q, r: (t, 0, r)),
-            pl.BlockSpec((1, 1, BLOCK_R), lambda t, q, r: (t, 0, r)),
-            pl.BlockSpec((1, 8), lambda t, q, r: (t, 0)),
-        ],
-        out_specs=[qvec] * 4,
-        out_shape=[jax.ShapeDtypeStruct((T, BQ), jnp.int32)] * 4,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((BLOCK_Q, W), lambda t, q, r, meta: (q, 0)),
+                pl.BlockSpec((BLOCK_Q, 1), lambda t, q, r, meta: (q, 0)),
+                pl.BlockSpec((1, W, BLOCK_R),
+                             lambda t, q, r, meta: (t, 0, r)),
+                pl.BlockSpec((1, 1, BLOCK_R),
+                             lambda t, q, r, meta: (t, 0, r)),
+            ],
+            out_specs=[qcol] * 4,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((T, BQ, 1), jnp.int32)] * 4,
         interpret=interpret,
-    )(patterns_t, plen[None, :], windows_t, sa[:, None, :], meta)
-    return out
+    )(meta, patterns, plen[:, None], windows_t, sa[:, None, :])
+    return tuple(o[:, :, 0] for o in out)

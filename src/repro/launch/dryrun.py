@@ -28,8 +28,6 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -167,14 +165,14 @@ def lower_sa_serve(mesh, routed: bool = False):
     B = 1024
 
     if routed:
-        @functools.partial(compat.shard_map, mesh=tmesh,
+        @functools.partial(jax.shard_map, mesh=tmesh,
                            in_specs=(P("tablets"), None, P("tablets"),
                                      P("tablets")),
                            out_specs=P("tablets"))
         def serve(sa_local, meta, patt, plen):
             return Q.query_routed(sa_local, meta, patt, plen, "tablets")
     else:
-        @functools.partial(compat.shard_map, mesh=tmesh,
+        @functools.partial(jax.shard_map, mesh=tmesh,
                            in_specs=(P("tablets"), None, P(), P()),
                            out_specs=P())
         def serve(sa_local, meta, patt, plen):
@@ -199,7 +197,7 @@ def lower_sa_build(mesh, method="bitonic"):
     m = ((SA.text_len + n_dev - 1) // n_dev)
     n_pad = m * n_dev
 
-    @functools.partial(compat.shard_map, mesh=tmesh, in_specs=(P("tablets"),),
+    @functools.partial(jax.shard_map, mesh=tmesh, in_specs=(P("tablets"),),
                        out_specs=(P("tablets"), P("tablets")))
     def build(codes_local):
         return build_suffix_array_sharded(

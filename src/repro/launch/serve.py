@@ -235,6 +235,8 @@ def main(argv=None):
             os.environ["XLA_FLAGS"] = f"{prev} {flag}".strip()
             print(f"[tune  ] XLA_FLAGS += {flag}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
 

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.models.config import ModelConfig
 from repro.models.layers import _dense_init, init_mlp, mlp
 
@@ -221,8 +220,8 @@ def _moe_ffn_ep(cfg: ModelConfig, p, x, mesh):
                 P("model", d_axes, None), P("model", d_axes, None),
                 P("model", None, d_axes))
     out_specs = (P(d_axes, None, None), P(d_axes))
-    fn = compat.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs)
     out, aux = fn(x, p["router"], p["wi"],
                   p.get("wg"), p["wo"])
     total = out

@@ -7,6 +7,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
+# Tests leave JAX's persistent compilation cache off: the entry points
+# they drive (serve.main, the benches) turn it on for real runs.  Set
+# before any jax import; child processes inherit it.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 
 def run_multidevice(code: str, n_devices: int = 8, timeout: int = 600):
     """Run ``code`` in a subprocess with n host devices (smoke tests and

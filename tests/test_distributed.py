@@ -9,7 +9,7 @@ def test_distributed_sorts(multidevice):
     multidevice("""
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core.dsort import bitonic_sort_sharded, sort_sharded_auto
 
@@ -20,6 +20,7 @@ for m, rng_max in [(64, 20), (256, 10**6)]:   # tie-heavy and near-unique
     vals = np.arange(8*m, dtype=np.int32)
     for fn in (lambda o: bitonic_sort_sharded(o, num_keys=1, axis_name='t'),
                lambda o: sort_sharded_auto(o, num_keys=1, axis_name='t')):
+        @jax.jit
         @partial(shard_map, mesh=mesh, in_specs=(P('t'), P('t')),
                  out_specs=(P('t'), P('t')))
         def run(k, v):
@@ -54,7 +55,7 @@ def test_distributed_scan_matches_local(multidevice):
     multidevice("""
 import jax, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core.tablet import build_tablet_store
 from repro.core import query as Q
@@ -137,7 +138,7 @@ def test_pipeline_parallelism(multidevice):
     multidevice("""
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.distributed.pipeline import pipeline_apply, stage_slice
 
@@ -177,7 +178,7 @@ def test_compressed_gradient_exchange(multidevice):
     multidevice("""
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.distributed.compression import compressed_pmean
 
@@ -215,7 +216,7 @@ def test_int8_on_the_wire(multidevice):
     multidevice("""
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.distributed.compression import compressed_pmean
 
@@ -243,7 +244,7 @@ def test_routed_query_matches_broadcast(multidevice):
     multidevice("""
 import jax, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from functools import partial
 from repro.core.tablet import build_tablet_store
 from repro.core import query as Q
@@ -256,8 +257,9 @@ for seed in [5, 6, 9]:
     pats = Q.random_patterns(64, 1, 10, seed=seed + 100)
     _, pp, pl = Q.encode_patterns(pats, 16)
 
+    @jax.jit
     @partial(shard_map, mesh=mesh,
-             in_specs=(P('t'), None, P('t'), P('t')), out_specs=P('t'))
+             in_specs=(P('t'), P(), P('t'), P('t')), out_specs=P('t'))
     def routed(sa_local, meta, patt, plen):
         return Q.query_routed(sa_local, meta, patt, plen, 't')
 

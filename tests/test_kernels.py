@@ -138,7 +138,8 @@ def test_unpack_2bit_batch_round_trip(B, L):
 
 
 # ---------------------------------------------------------------------------
-# FM backward-search kernel vs the jnp oracle vs brute force
+# FM backward search (the frozen tier's read, XLA on every backend) vs
+# brute force and the binary-search path
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("n,nq", [(130, 40), (2048, 200)])
 def test_fm_scan_pallas_matches_oracle(n, nq):
@@ -150,17 +151,17 @@ def test_fm_scan_pallas_matches_oracle(n, nq):
     pats = Q.random_patterns(nq, 1, 12, seed=nq)
     _, pp, pl = Q.encode_patterns(pats, 16)
     syms = FM.syms_from_packed(pp, pl, pp.shape[1] * 16)
-    lo_o, hi_o = FM.search_syms(fm.arrays, syms)        # jnp oracle
-
-    padded, B = ops._pad_to(syms, FM.BLOCK_Q, 1, fill=-1)
-    lo_k, hi_k = FM.fm_scan_pallas(padded, fm.arrays.bwt, fm.arrays.occ,
-                                   FM.pallas_meta(fm.arrays),
-                                   interpret=True)      # Pallas kernel
-    np.testing.assert_array_equal(np.asarray(lo_k)[:B], np.asarray(lo_o))
-    np.testing.assert_array_equal(np.asarray(hi_k)[:B], np.asarray(hi_o))
+    lo, hi = FM.search_syms(fm.arrays, syms)
+    count = np.asarray(hi) - np.asarray(lo)
 
     cc = np.asarray(codes).astype(np.int32)
-    count = np.asarray(hi_o) - np.asarray(lo_o)
     for i, p in enumerate(pats):
         want, _ = Q.brute_force_count(cc, codec.encode_dna(p).astype(np.int32))
         assert int(count[i]) == want, p
+
+    # lo - 1 is the real-SA lower bound the binary search reports
+    res = Q.query(build_tablet_store(codes), pp, pl)
+    f = np.asarray(res.found)
+    np.testing.assert_array_equal(count, np.asarray(res.count))
+    np.testing.assert_array_equal(np.asarray(lo)[f] - 1,
+                                  np.asarray(res.first_rank)[f])

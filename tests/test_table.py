@@ -362,3 +362,25 @@ want = json.load(open(ROOT + '/expect2.json'))
 assert t.scan(pats).count.tolist() == want['count']
 print('OK')
 """, n_devices=1)
+
+
+@pytest.mark.parametrize("n,block,k", [(1, 4, 3), (100, 8, 1),
+                                       (5000, 64, 8), (5000, 7, 20)])
+def test_rangemin_matches_brute_force(n, block, k):
+    """Block-minimum range reads (the base tier's text-order first_pos and
+    top-k) equal a full gather of every row in the range."""
+    from repro.core.rangemin import block_minima, range_min, range_smallest
+    rng = np.random.default_rng(n + block)
+    vals = rng.permutation(n).astype(np.int32)
+    bmin = block_minima(vals, block)
+    lo = rng.integers(0, n, size=60)
+    count = np.minimum(rng.integers(0, n + 1, size=60), n - lo)
+    count[:3] = 0
+    got = range_min(vals.__getitem__, bmin, lo, count, block)
+    for i in range(lo.size):
+        seg = vals[lo[i]:lo[i] + count[i]]
+        assert got[i] == (seg.min() if seg.size else -1)
+        np.testing.assert_array_equal(
+            range_smallest(vals.__getitem__, bmin, lo[i], count[i], k,
+                           block),
+            np.sort(seg)[:k])

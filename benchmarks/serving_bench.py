@@ -13,7 +13,10 @@ Three questions, one scripted load run (docs/observability.md):
   the inline fast path: per-call latency is measured with the tracers
   enabled vs disabled (min-of-alternating-reps to kill scheduler
   noise) and reported as ``trace_overhead_x`` (gated, lower-better);
-  results are checked bit-identical across both arms;
+  results are checked bit-identical across both arms.  The cost of one
+  span alone is reported in microseconds (``span_us_disabled``,
+  ``span_us``, and ``span_us_profiled`` while a ``jax.profiler`` trace
+  records, when every span is also a profiler annotation);
 * **tuned launcher effect** — a fresh subprocess imports jax and runs
   one dispatch under the default env vs the ``--tuned`` preset
   (TF_CPP_MIN_LOG_LEVEL=4 + tcmalloc report threshold; the LD_PRELOAD
@@ -138,6 +141,38 @@ def _served_load(args, db, table, name: str, feed_path: str) -> dict:
     }
 
 
+SPAN_COST_SPANS = 100_000        # spans a round of the per-span cost
+SPAN_COST_ROUNDS = 5             # the figure is the best round
+
+
+def _us_per_span(tracer) -> float:
+    best = float("inf")
+    for _ in range(SPAN_COST_ROUNDS):
+        t0 = time.perf_counter()
+        for _ in range(SPAN_COST_SPANS):
+            with tracer.span("dispatch"):
+                pass
+        best = min(best, time.perf_counter() - t0)
+    return best / SPAN_COST_SPANS * 1e6
+
+
+def _span_cost() -> dict:
+    """Microseconds per span: a disabled tracer, an enabled one with no
+    profiler session, and an enabled one while a trace records (written
+    to a temporary directory and dropped)."""
+    import jax
+
+    from repro.serving.trace import Tracer
+
+    out = {"span_us_disabled": _us_per_span(Tracer("table",
+                                                   enabled=False)),
+           "span_us": _us_per_span(Tracer("table"))}
+    with tempfile.TemporaryDirectory(prefix="serving_bench_trace_") as d:
+        with jax.profiler.trace(d):
+            out["span_us_profiled"] = _us_per_span(Tracer("table"))
+    return {k: round(v, 4) for k, v in out.items()}
+
+
 def _overhead(args, db, table, name: str) -> dict:
     """Per-call fast-path latency, tracers enabled vs disabled —
     min-of-alternating-reps so one GC hiccup can't fake a regression."""
@@ -237,6 +272,7 @@ def run(args) -> dict:
     try:
         served = _served_load(args, db, table, name, feed_path)
         overhead = _overhead(args, db, table, name)
+        span_cost = _span_cost()
         if args.feed_out:
             os.makedirs(os.path.dirname(os.path.abspath(args.feed_out)),
                         exist_ok=True)
@@ -255,6 +291,7 @@ def run(args) -> dict:
         "results": {
             "served": served,
             **overhead,
+            **span_cost,
             **tuned,
         },
     }

@@ -309,8 +309,9 @@ class QueryScheduler:
         self._busy = 0                 # waves executing right now
         self.stats = SchedulerStats()
         # span histograms (stats_snapshot()["latency"]): coalesce_wait,
-        # admission, execute — docs/observability.md defines each
-        self.tracer = Tracer()
+        # admission, execute, window, deliver — docs/observability.md
+        # defines each
+        self.tracer = Tracer("sched")
         self._cv = threading.Condition()
         # one lock PER TABLE OBJECT serializes that table's scans and
         # client-side writes: the worker thread draining windowed waves
@@ -413,16 +414,17 @@ class QueryScheduler:
                     self._cv.wait()
                 if not self._pending and self._closed:
                     return
-                wave_open = self._pending[0].t_submit
-                while (not self._closed
-                       and len(self._pending) < self.max_batch):
-                    now = time.perf_counter()
-                    left = self._deadline_of(wave_open) - now
-                    if left <= 0:
-                        break
-                    self._cv.wait(timeout=left)
-                wave = self._pending[:self.max_batch]
-                del self._pending[:len(wave)]
+                with self.tracer.span("window"):
+                    wave_open = self._pending[0].t_submit
+                    while (not self._closed
+                           and len(self._pending) < self.max_batch):
+                        now = time.perf_counter()
+                        left = self._deadline_of(wave_open) - now
+                        if left <= 0:
+                            break
+                        self._cv.wait(timeout=left)
+                    wave = self._pending[:self.max_batch]
+                    del self._pending[:len(wave)]
                 self._busy += 1
             try:
                 self._execute(wave)
@@ -488,7 +490,8 @@ class QueryScheduler:
         and ``ewma_gap_ms`` (the smoothed inter-arrival gap, ``None``
         before two submits) — plus ``latency``: the scheduler tracer's
         span histograms (``coalesce_wait`` / ``admission`` /
-        ``execute``) — schema in docs/client_api.md and
+        ``execute`` / ``window`` / ``deliver``) — schema in
+        docs/client_api.md and
         docs/observability.md."""
         with self._cv:
             d = self.stats.as_dict()
@@ -594,9 +597,10 @@ class QueryScheduler:
                 self.stats.coalesced_queries += len(live)
             self.stats.max_batch_patterns = max(
                 self.stats.max_batch_patterns, n)
-        for p, (lo, hi) in zip(live, spans):
-            p.future._set(self._slice(p.query, out, lo, hi, n,
-                                      (now - p.t_submit) * 1e3))
+        with tr.span("deliver"):
+            for p, (lo, hi) in zip(live, spans):
+                p.future._set(self._slice(p.query, out, lo, hi, n,
+                                          (now - p.t_submit) * 1e3))
 
     @staticmethod
     def _slice(query: Query, out: ScanOutcome, lo: int, hi: int,

@@ -149,7 +149,7 @@ class SuffixTable:
         self._codes = np.asarray(codes)
         # span histograms (stats()["latency"]): created before the
         # planner so freeze/compaction rebinds keep one shared tracer
-        self.tracer = Tracer()
+        self.tracer = Tracer("table")
         self._metrics: Optional[MetricsEmitter] = None
 
         if _store is not None:                       # from_store: adopt as-is
@@ -654,19 +654,31 @@ class SuffixTable:
         return self._tiers
 
     def _scan_tiers(self, patt, plen, *, mode=None, n_real=None):
-        """One fused merged dispatch: (merged MatchResult, TierScanResult
-        | None, delta positions per query | None, base-only count)."""
+        """One fused merged dispatch and the wait for its result, as host
+        arrays of the first ``n_real`` rows: (merged count, base-SA
+        ``first_rank``, base-only count, the delta tiers' ``(less,
+        matches)`` | None — see :meth:`_delta`)."""
         merged, tres = self.planner.scan_tiers(
             self._tierset(), patt, plen, mode=mode, n_real=n_real)
-        B = int(np.asarray(plen).shape[0]) if n_real is None else int(n_real)
-        count = np.asarray(merged.count).astype(np.int64)[:B]
-        if tres is None:
-            return merged, None, None, count
-        delta = self._tiers.delta_positions(tres.less, tres.matches,
-                                            plen, n_real=B)
-        base_count = count - np.asarray(
-            tres.count)[:, :B].astype(np.int64).sum(axis=0)
-        return merged, tres, delta, base_count
+        B = len(plen) if n_real is None else int(n_real)
+        # the first host conversions force the launch: the device's work
+        # and the copy back land in this span
+        with self.tracer.span("wait"):
+            count = np.asarray(merged.count).astype(np.int64)[:B]
+            base_rank = np.asarray(merged.first_rank)[:B]
+            if tres is None:
+                return count, base_rank, count, None
+            tcount = np.asarray(tres.count)
+            tiers = (np.asarray(tres.less), np.asarray(tres.matches))
+        base_count = count - tcount[:, :B].astype(np.int64).sum(axis=0)
+        return count, base_rank, base_count, tiers
+
+    def _delta(self, tiers, plen, B: int):
+        """Per query, the ascending delta-tier positions (None without
+        delta tiers) from :meth:`_scan_tiers`'s ``(less, matches)``."""
+        if tiers is None:
+            return None
+        return self._tiers.delta_positions(*tiers, plen, n_real=B)
 
     def _base_rows(self):
         """(row -> text position getter, its block minima, row offset of
@@ -736,8 +748,7 @@ class SuffixTable:
         ``planner.stats.pad_slots`` (slot accounting under
         ``bucketed_batches``), never to ``queries``.
         """
-        plen_np = np.asarray(plen)
-        B = int(plen_np.shape[0])
+        B = len(plen)
         if B == 0:
             return ScanOutcome(
                 found=np.zeros(0, bool), count=np.zeros(0, np.int64),
@@ -746,23 +757,28 @@ class SuffixTable:
                            if top_k else None))
         tr = self.tracer
         t_all = time.monotonic_ns()
-        patt_np = np.asarray(patt)
-        bucket = 1 << (B - 1).bit_length() if B > 1 else 1
-        if bucket != B:
-            reps = bucket - B
-            patt_np = np.concatenate(
-                [patt_np, np.repeat(patt_np[:1], reps, axis=0)])
-            plen_np = np.concatenate(
-                [plen_np, np.repeat(plen_np[:1], reps)])
-        # "dispatch" covers the fused launch; any async device wait is
-        # forced (and therefore timed) by the host conversions inside
-        # _scan_tiers, so "merge" below is pure host-side reduction
+        # "dispatch" is the device read: "upload" (the batch as host
+        # arrays — copied back first where encode left it on the device —
+        # bucket padding and the copies to the device), the planner's
+        # "plen_check" and "dispatch_<mode>" (the launch) and "wait" (the
+        # device's work and the copy back, forced by _scan_tiers' first
+        # host conversions); "merge" below is pure host-side reduction
         with tr.span("dispatch"):
-            merged, _tres, delta, base_count = self._scan_tiers(
-                jnp.asarray(patt_np), jnp.asarray(plen_np), n_real=B)
+            with tr.span("upload"):
+                patt_np, plen_np = np.asarray(patt), np.asarray(plen)
+                bucket = 1 << (B - 1).bit_length() if B > 1 else 1
+                if bucket != B:
+                    reps = bucket - B
+                    patt_np = np.concatenate(
+                        [patt_np, np.repeat(patt_np[:1], reps, axis=0)])
+                    plen_np = np.concatenate(
+                        [plen_np, np.repeat(plen_np[:1], reps)])
+                patt_dev = jnp.asarray(patt_np)
+                plen_dev = jnp.asarray(plen_np)
+            count, base_rank, base_count, tiers = self._scan_tiers(
+                patt_dev, plen_dev, n_real=B)
         with tr.span("merge"):
-            count = np.asarray(merged.count).astype(np.int64)[:B]
-            base_rank = np.asarray(merged.first_rank)[:B]
+            delta = self._delta(tiers, plen_np, B)
             first_pos = self._base_min_positions(base_count, base_rank)
             positions = (np.full((B, top_k), -1, np.int64)
                          if top_k else None)
@@ -798,27 +814,30 @@ class SuffixTable:
         first_pos = np.full(B, -1, np.int64)
         positions = (np.full((B, top_k), -1, np.int64) if top_k else None)
         miss_idx: list[int] = []
-        for i, pat in enumerate(patterns):
-            hit = self._cache.get(pat, top_k)
-            if hit is not None:
-                count[i], first_pos[i] = hit[0], hit[1]
-                if top_k:
-                    positions[i] = hit[2]
-            else:
-                miss_idx.append(i)
+        tr = self.tracer
+        with tr.span("cache_lookup"):
+            for i, pat in enumerate(patterns):
+                hit = self._cache.get(pat, top_k)
+                if hit is not None:
+                    count[i], first_pos[i] = hit[0], hit[1]
+                    if top_k:
+                        positions[i] = hit[2]
+                else:
+                    miss_idx.append(i)
         if miss_idx:
-            with self.tracer.span("encode"):
+            with tr.span("encode"):
                 patt, plen = self.planner.encode(
                     [patterns[i] for i in miss_idx])
             sub = self.scan_batch(patt, plen, top_k=top_k)
-            for j, i in enumerate(miss_idx):
-                count[i] = sub.count[j]
-                first_pos[i] = sub.first_pos[j]
-                row = sub.positions[j] if top_k else None
-                if top_k:
-                    positions[i] = row
-                self._cache.put(patterns[i], int(count[i]),
-                                int(first_pos[i]), top_k, row)
+            with tr.span("cache_fill"):
+                for j, i in enumerate(miss_idx):
+                    count[i] = sub.count[j]
+                    first_pos[i] = sub.first_pos[j]
+                    row = sub.positions[j] if top_k else None
+                    if top_k:
+                        positions[i] = row
+                    self._cache.put(patterns[i], int(count[i]),
+                                    int(first_pos[i]), top_k, row)
         return ScanOutcome(found=count > 0, count=count,
                            first_pos=first_pos, positions=positions)
 
@@ -839,9 +858,10 @@ class SuffixTable:
         if limit is not None and limit <= 0:
             raise ValueError(f"limit must be positive, got {limit}")
         patt, plen = self.planner.encode([pattern])
-        merged, _tres, delta, base_count = self._scan_tiers(patt, plen,
-                                                            n_real=1)
-        run = self._base_slice(base_count, np.asarray(merged.first_rank), 0)
+        _count, base_rank, base_count, tiers = self._scan_tiers(
+            patt, plen, n_real=1)
+        run = self._base_slice(base_count, base_rank, 0)
+        delta = self._delta(tiers, plen, 1)
         g = delta[0] if delta is not None else np.zeros((0,), np.int64)
         cand = np.concatenate([run, g]) if g.size else run
         cand = cand[cand > after]

@@ -286,7 +286,7 @@ class ScanPlanner:
         self.stats = PlannerStats()
         # shared with the owning table so span histograms survive
         # rebind/recreation across freeze and compaction
-        self.tracer = tracer if tracer is not None else Tracer()
+        self.tracer = tracer if tracer is not None else Tracer("table")
         self._cache = TopKCache(self.cache_size)
         self._sa_host: Optional[np.ndarray] = None
         self._sa_bmin: Optional[np.ndarray] = None
@@ -437,7 +437,9 @@ class ScanPlanner:
         if n_real is not None and not 0 <= n_real <= B:
             raise ValueError(f"n_real={n_real} out of range for batch {B}")
         if B:
-            max_plen = int(np.max(np.asarray(plen)))
+            # a leaf span: a device ``plen`` is copied back to the host
+            with self.tracer.span("plen_check"):
+                max_plen = int(np.max(np.asarray(plen)))
             if max_plen > self.max_pattern_len:
                 raise ValueError(
                     f"pattern length {max_plen} exceeds max_pattern_len="
@@ -490,9 +492,9 @@ class ScanPlanner:
             z = jnp.zeros((0,), jnp.int32)
             return MatchResult(found=z.astype(bool), count=z,
                                first_rank=z, first_pos=z)
-        # NOTE jax dispatch is async: this span measures enqueue + any
-        # host work the executor does; device wait is paid (and traced)
-        # by whichever downstream span first forces the result
+        # NOTE jax dispatch is async: this span is the launch (enqueue +
+        # any host work the executor does); the table's "wait" span pays
+        # the device's work when it first forces the result
         with self.tracer.span("dispatch_" + chosen):
             res = self._executor(chosen)(patt, plen)
         if chosen != MODE_ROUTED or not retry:

@@ -22,34 +22,7 @@ import json
 import os
 import threading
 import time
-from collections import deque
 from typing import Callable, Optional
-
-
-class LatencyWindow:
-    """Rolling window of service latencies with p50/p95 quantiles."""
-
-    def __init__(self, size: int = 512):
-        self._window: deque = deque(maxlen=size)
-        self._lock = threading.Lock()
-        self.total = 0
-
-    def record(self, ms: float) -> None:
-        with self._lock:
-            self._window.append(float(ms))
-            self.total += 1
-
-    def quantiles(self) -> dict:
-        with self._lock:
-            data = sorted(self._window)
-        if not data:
-            return {"p50_ms": 0.0, "p95_ms": 0.0, "p99_ms": 0.0, "n": 0}
-
-        def q(frac: float) -> float:
-            return data[min(len(data) - 1, int(frac * len(data)))]
-
-        return {"p50_ms": round(q(0.50), 4), "p95_ms": round(q(0.95), 4),
-                "p99_ms": round(q(0.99), 4), "n": len(data)}
 
 
 def append_line(path: str, record: dict) -> None:

@@ -42,7 +42,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.serving import rpc
-from repro.serving.metrics import LatencyWindow, MetricsEmitter
+from repro.serving.metrics import MetricsEmitter
 from repro.serving.tablet_server import encode_pattern_rows
 from repro.serving.trace import Tracer
 
@@ -142,11 +142,11 @@ class TabletRouter:
         self.failovers = 0
         self.quota_shed = 0
         self.rpcs = 0
-        self._latency = LatencyWindow()
-        # span histograms (stats()["latency"]): dispatch_remote (one
-        # logical tablet read: hedge + failover walk) and hedge_wait
-        # (hedge fired -> first success) — docs/observability.md
-        self.tracer = Tracer()
+        # span histograms (stats()["latency"]): request (one merged
+        # scan), dispatch_remote (one logical tablet read: hedge +
+        # failover walk) and hedge_wait (hedge fired -> first success)
+        # — docs/observability.md
+        self.tracer = Tracer("router")
         self._quotas: dict[str, TokenBucket] = {}
         self.emitter = None
         if metrics_path is not None:
@@ -289,8 +289,13 @@ class TabletRouter:
         """Scan a decoded (B, L) int32 batch across the plane and merge
         to single-process semantics: count = Σ per-tablet counts (+ the
         owner's delta count), first_pos = min, positions = ascending
-        top-k of the union (docs/serving_plane.md proves each)."""
-        t0 = time.perf_counter()
+        top-k of the union (docs/serving_plane.md proves each).  The
+        whole call is one ``request`` span, the feed row's latency."""
+        with self.tracer.span("request"):
+            return self._scan_rows(rows, lens, top_k)
+
+    def _scan_rows(self, rows: np.ndarray, lens: np.ndarray,
+                   top_k: int) -> dict:
         rows = np.ascontiguousarray(rows).astype(np.int32)
         lens = np.asarray(lens).astype(np.int64)
         B = rows.shape[0]
@@ -342,7 +347,6 @@ class TabletRouter:
                         cand = np.partition(cand, top_k - 1)[:top_k]
                     cand.sort()
                     positions[i, :cand.shape[0]] = cand
-        self._latency.record((time.perf_counter() - t0) * 1e3)
         return {"found": count > 0, "count": count, "first_pos": first,
                 "positions": positions}
 
@@ -394,7 +398,7 @@ class TabletRouter:
                   "failovers": self.failovers,
                   "quota_shed": self.quota_shed,
                   "hedge_enabled": self.hedge_enabled}
-        st.update(self._latency.quantiles())
+        st.update(self.tracer.headline("request"))
         st["latency"] = self.tracer.snapshot()
         return st
 

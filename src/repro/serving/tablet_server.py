@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.api.wal import read_segment
 from repro.serving import rpc
-from repro.serving.metrics import LatencyWindow, MetricsEmitter
+from repro.serving.metrics import MetricsEmitter
 from repro.serving.trace import Tracer
 
 _DNA = {c: i for i, c in enumerate("ACGT")}
@@ -394,10 +394,10 @@ class TabletWorker:
         # one logical device per worker: scan execution is serialized,
         # like a single-accelerator planner dispatch queue
         self._device_lock = threading.Lock()
-        self._latency = LatencyWindow()
-        # per-op span histograms (stats()["latency"]): scan / locate /
-        # stats service time, same snapshot schema as every other tier
-        self.tracer = Tracer()
+        # span histograms (stats()["latency"]): service (every answered
+        # request) and one per op (scan / locate / stats), same snapshot
+        # schema as every other tier
+        self.tracer = Tracer("worker")
         self._queries = 0
         self._rpcs = 0
         self._t0 = time.time()
@@ -412,7 +412,6 @@ class TabletWorker:
 
     def _observe(self, op: str, service_ms: float, shed: bool) -> None:
         if not shed:
-            self._latency.record(service_ms)
             self.tracer.record(str(op), service_ms)
 
     def _device_execute(self, n_patterns: int):
@@ -430,7 +429,7 @@ class TabletWorker:
 
     def stats(self) -> dict:
         st = self.index.stats()
-        st.update(self._latency.quantiles())
+        st.update(self.tracer.headline("service"))
         st.update({"role": "worker", "replica": self.replica,
                    "pid": os.getpid(), "queries": self._queries,
                    "rpcs": self._rpcs,
@@ -443,6 +442,10 @@ class TabletWorker:
 
     # -- request handling -----------------------------------------------------
     def handle(self, msg: dict) -> dict:
+        with self.tracer.span("service"):
+            return self._handle(msg)
+
+    def _handle(self, msg: dict) -> dict:
         op = msg.get("op")
         if op == "ping":
             return {"status": "ok", "pid": os.getpid(),

@@ -9,6 +9,14 @@ rings to rolling p50/p95/p99 histograms.  The snapshot is what
 feed exports, so one schema describes in-process, scheduled, and
 multi-process serving alike (docs/observability.md).
 
+Every span is also a profiler annotation named ``<component>.<name>``
+(``table.dispatch``, ``sched.window``): while a ``jax.profiler`` trace
+is active it lands on the host plane, on the same clock as the
+device's operations, so an idle gap on the chip can be put down to the
+span the host was in.  The annotation is made only while a profiler
+session records (one ``TraceMe.is_enabled()`` check a span), so outside
+a trace a span costs what it did before it had one.
+
 Design constraints (the read path is the hot path):
 
 * Recording a span is two ``time.monotonic_ns()`` calls, one float
@@ -19,7 +27,8 @@ Design constraints (the read path is the hot path):
   rolling histogram tolerates by construction.
 * ``Tracer(enabled=False)`` (or ``tracer.enabled = False`` at runtime)
   swaps ``span()`` for a shared no-op context, so a disabled tracer
-  costs one attribute check per call site.
+  costs one attribute check per call site and emits nothing, neither
+  to its histograms nor to the profiler.
 * Buffers are preallocated numpy float64 rings (default 2048 samples
   per span) — memory is bounded no matter how long the process serves.
 
@@ -32,6 +41,9 @@ from __future__ import annotations
 import time
 
 import numpy as np
+# the class behind jax.profiler.TraceAnnotation; taken from jaxlib so
+# the numpy-only plane processes need not import jax for it
+from jaxlib._profiler import TraceMe as _Annotation
 
 __all__ = ["SpanHistogram", "Tracer"]
 
@@ -69,8 +81,7 @@ class SpanHistogram:
         return self._n
 
     def quantiles(self) -> dict:
-        """Rolling p50/p95/p99 over the ring window (same empirical
-        quantile rule as ``metrics.LatencyWindow``: the sorted sample
+        """Rolling p50/p95/p99 over the ring window (the sorted sample
         at index ``int(frac * n)``, clamped)."""
         n = min(self._n, self._size)
         if n == 0:
@@ -105,6 +116,27 @@ class _Span:
         return False
 
 
+class _AnnotatedSpan(_Span):
+    """A timed region inside its profiler annotation, for a span opened
+    while a profiler session records."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, tracer: "Tracer", name: str, label: str):
+        super().__init__(tracer, name)
+        self._ann = _Annotation(label)
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        super().__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
 class _NullSpan:
     """Shared no-op context for disabled tracers."""
 
@@ -121,22 +153,32 @@ _NULL_SPAN = _NullSpan()
 
 
 class Tracer:
-    """Named span histograms for one component (table, scheduler,
-    router).  ``span(name)`` times a region; ``record(name, ms)`` logs
-    an externally measured duration (e.g. a queue wait computed from a
-    stored submit timestamp); ``snapshot()`` is the ``stats()
-    ["latency"]`` payload."""
+    """Named span histograms for one component (``table``, ``sched``,
+    ``router``, ``worker``).  ``span(name)`` times a region and marks
+    it in the profiler as ``<component>.<name>``; ``record(name, ms)``
+    logs an externally measured duration (e.g. a queue wait computed
+    from a stored submit timestamp) to the histogram only, since a past
+    interval cannot be put into a trace; ``snapshot()`` is the
+    ``stats()["latency"]`` payload, keyed by the bare span names."""
 
-    def __init__(self, *, ring_size: int = _DEFAULT_RING,
-                 enabled: bool = True):
+    def __init__(self, component: str, *,
+                 ring_size: int = _DEFAULT_RING, enabled: bool = True):
+        self.component = str(component)
         self.enabled = bool(enabled)
         self._ring_size = int(ring_size)
         self._spans: dict[str, SpanHistogram] = {}
+        self._labels: dict[str, str] = {}     # span name -> profiler name
 
     def span(self, name: str):
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name)
+        if not _Annotation.is_enabled():
+            return _Span(self, name)
+        label = self._labels.get(name)
+        if label is None:
+            label = self._labels.setdefault(name,
+                                            f"{self.component}.{name}")
+        return _AnnotatedSpan(self, name, label)
 
     def record(self, name: str, ms: float) -> None:
         if not self.enabled:
@@ -147,6 +189,14 @@ class Tracer:
             hist = self._spans.setdefault(name,
                                           SpanHistogram(self._ring_size))
         hist.record(float(ms))
+
+    def headline(self, name: str) -> dict:
+        """``{p50_ms, p95_ms, p99_ms, n}`` of span ``name`` (zeros before
+        its first sample): the top-level latency scalars of a plane
+        feed row."""
+        hist = self._spans.get(name) or SpanHistogram(1)
+        q = hist.quantiles()
+        return {k: q[k] for k in ("p50_ms", "p95_ms", "p99_ms", "n")}
 
     def snapshot(self) -> dict:
         """``{span_name: {p50_ms, p95_ms, p99_ms, n, total, sum_ms}}``,

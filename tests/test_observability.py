@@ -66,7 +66,7 @@ def test_empty_histogram_and_bad_size():
 
 
 def test_tracer_spans_measure_and_snapshot_sorts():
-    tr = Tracer()
+    tr = Tracer("table")
     with tr.span("zz_outer"):
         with tr.span("aa_inner"):
             time.sleep(0.01)
@@ -84,7 +84,7 @@ def test_tracer_spans_measure_and_snapshot_sorts():
 
 
 def test_disabled_tracer_is_shared_noop():
-    tr = Tracer(enabled=False)
+    tr = Tracer("table", enabled=False)
     assert tr.span("a") is tr.span("b")       # one shared null context
     with tr.span("a"):
         pass
@@ -129,6 +129,108 @@ def test_scheduler_and_planner_spans_appear():
         # planner spans ride the table's tracer, one dispatch_* per mode
         tbl_lat = st["tables"]["t"]["latency"]
         assert any(k.startswith("dispatch") for k in tbl_lat)
+
+
+# the spans a read leaves in a profiler trace, by their profiler names
+READ_SPANS = {"table.cache_lookup", "table.encode", "table.upload",
+              "table.plen_check", "table.dispatch_single", "table.wait",
+              "table.dispatch", "table.merge", "table.cache_fill",
+              "sched.execute", "sched.window", "sched.deliver"}
+
+
+def _traced_reads(tmp_path, *, enabled=True):
+    """Inline count and scan(top_k) queries plus a windowed submit on a
+    tiny table under ``jax.profiler.trace``; returns the program's host
+    events ``{name: [(start_ns, end_ns), ...]}`` and both tracers'
+    ``stats()["latency"]`` keys."""
+    import jax
+
+    sys.path.insert(0, os.path.join(REPO, "chipbench"))
+    import trace_reduce
+
+    with Database.in_memory(adaptive_window=False) as db:
+        table = db.attach("t", SuffixTable.from_codes(
+            codec.random_dna(10_000, seed=4), is_dna=True))
+        for q in (Query.count("t", ["ACGT", "TTAG"]),
+                  Query.scan("t", ["GATC"], top_k=3)):
+            assert db.query(q).ok             # compile outside the trace
+        table.clear_cache()
+        for tr in (table.tracer, db.scheduler.tracer):
+            tr.reset()
+            tr.enabled = enabled
+        with jax.profiler.trace(str(tmp_path / "trace")):
+            assert db.query(Query.count("t", ["ACGTA", "TTAGC"])).ok
+            assert db.query(Query.scan("t", ["GATCA", "CC"], top_k=3)).ok
+            assert db.submit(Query.count("t", ["GGAT"])).result(30.0).ok
+        keys = (set(table.stats()["latency"]),
+                set(db.scheduler.stats_snapshot()["latency"]))
+    events: dict[str, list] = {}
+    trace = trace_reduce.from_xplane(
+        trace_reduce.find_xplane(str(tmp_path / "trace")))
+    for plane in trace["planes"]:
+        if plane["name"] != trace_reduce.HOST_PLANE:
+            continue
+        for line in plane["lines"]:
+            for name, start, dur in line["events"]:
+                if name.startswith(("table.", "sched.")):
+                    events.setdefault(name, []).append((start, start + dur))
+    return events, keys
+
+
+def test_read_spans_land_in_the_profiler_trace(tmp_path):
+    events, _keys = _traced_reads(tmp_path)
+    assert READ_SPANS <= set(events)
+    # the device read splits into leaves inside its dispatch span
+    outer = events["table.dispatch"]
+    for leaf in ("table.upload", "table.dispatch_single", "table.wait"):
+        for lo, hi in events[leaf]:
+            assert any(a <= lo and hi <= b for a, b in outer), leaf
+
+
+def test_disabled_tracer_emits_no_profiler_spans(tmp_path):
+    events, keys = _traced_reads(tmp_path, enabled=False)
+    assert events == {}
+    assert keys == (set(), set())
+
+
+def test_latency_keys_keep_their_bare_names(tmp_path):
+    _events, (table_keys, sched_keys) = _traced_reads(tmp_path)
+    assert table_keys == {"cache_lookup", "encode", "upload", "plen_check",
+                          "dispatch_single", "wait", "dispatch", "merge",
+                          "cache_fill", "total"}
+    assert sched_keys == {"execute", "window", "deliver", "coalesce_wait"}
+
+
+def test_span_annotation_is_the_profilers_trace_annotation(tmp_path):
+    import jax.profiler
+
+    from repro.serving import trace
+    assert issubclass(jax.profiler.TraceAnnotation, trace._Annotation)
+    tr = Tracer("router")
+    # no profiler session: a plain timed span, no annotation made
+    span = tr.span("request")
+    assert type(span) is trace._Span
+    with span:
+        pass
+    assert tr._labels == {}
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        span = tr.span("request")
+        assert isinstance(span, trace._AnnotatedSpan)
+        with span:
+            pass
+    assert tr._labels == {"request": "router.request"}
+    assert tr.snapshot()["request"]["n"] == 2
+
+
+def test_headline_is_the_feed_rows_latency_scalars():
+    tr = Tracer("worker")
+    assert tr.headline("service") == {"p50_ms": 0.0, "p95_ms": 0.0,
+                                      "p99_ms": 0.0, "n": 0}
+    for ms in (1.0, 2.0, 3.0, 4.0):
+        tr.record("service", ms)
+    snap = tr.snapshot()["service"]
+    assert tr.headline("service") == {k: snap[k] for k in
+                                      ("p50_ms", "p95_ms", "p99_ms", "n")}
 
 
 def test_stats_to_feed_round_trip(tmp_path):

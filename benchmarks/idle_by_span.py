@@ -19,8 +19,10 @@ The report (one JSON object, written to
 standard output) holds the harness's result line, the idle split in
 seconds and as a share of idle, the midpoint rule's names for
 comparison, each program span's count and summed milliseconds inside the
-window, and the requests (``chipbench.request``) and scheduler waves
-(``sched.execute``) the window held.  It refuses to run (exit 2) unless
+window, the requests (``chipbench.request``) and scheduler waves
+(``sched.execute``) the window held, and the table planner's counters
+(``PlannerStats``, ``input_copybacks`` among them) after the window and
+their change over it.  It refuses to run (exit 2) unless
 JAX sees a TPU with the cell's number of chips.
 """
 from __future__ import annotations
@@ -167,6 +169,15 @@ def _shares(named: dict, idle: float) -> list:
             for k, v in sorted(named.items(), key=lambda kv: -kv[1])]
 
 
+def planner_counters(before: dict, after: dict) -> dict:
+    """The table planner's whole-number counters after the window
+    (``total``, set-up's warm-up included) and their change over it
+    (``window``)."""
+    keys = [k for k, v in after.items() if isinstance(v, int)]
+    return {"total": {k: after[k] for k in keys},
+            "window": {k: after[k] - before.get(k, 0) for k in keys}}
+
+
 def report(trace: dict) -> dict:
     """The idle split, the midpoint rule's names and the span sums of
     one traced window."""
@@ -211,15 +222,26 @@ def main(argv=None) -> int:
                      f"for {cell.chips}")
         return 2
 
-    # keep the trace the harness reads, so it is read once
+    # keep the trace the harness reads, so it is read once, and the
+    # planner's counters the metric readers are handed
     kept = {}
     read = trace_reduce.from_xplane
+    load_reader = harness.load_reader
 
     def keep(path):
         kept["trace"] = read(path)
         return kept["trace"]
 
+    def keep_planner(bench_dir, metric):
+        reader = load_reader(bench_dir, metric)
+
+        def read_metric(ctx):
+            kept["planner"] = ctx["planner"]
+            return reader(ctx)
+        return read_metric
+
     trace_reduce.from_xplane = keep
+    harness.load_reader = keep_planner
     work = os.path.join(chip_run.STATE, "idle_by_span", args.workload)
     try:
         result = harness.run_cell(cell, seed=args.seed,
@@ -228,10 +250,12 @@ def main(argv=None) -> int:
                                   log=chip_run.log)
     finally:
         trace_reduce.from_xplane = read
+        harness.load_reader = load_reader
         shutil.rmtree(work, ignore_errors=True)
     out = {"workload": args.workload, "seed": args.seed,
            "seconds": args.seconds, "result": result,
-           **report(kept["trace"])}
+           **report(kept["trace"]),
+           "planner": planner_counters(*kept.get("planner", ({}, {})))}
     path = os.path.join(
         CHECKOUT, "chiprun_out",
         f"idle_by_span_{args.workload}_{args.seed}.json")
@@ -240,7 +264,7 @@ def main(argv=None) -> int:
         json.dump(out, f, indent=1)
     print(json.dumps({k: out[k] for k in (
         "workload", "seed", "idle_s", "harness_share_by_span",
-        "harness_share_by_midpoint", "requests", "waves")}
+        "harness_share_by_midpoint", "requests", "waves", "planner")}
         | {"idle_by_span": out["idle_by_span"][:8],
            "correct": result["correct"], "out": path}), flush=True)
     return 0

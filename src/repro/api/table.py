@@ -757,14 +757,18 @@ class SuffixTable:
                            if top_k else None))
         tr = self.tracer
         t_all = time.monotonic_ns()
-        # "dispatch" is the device read: "upload" (the batch as host
-        # arrays — copied back first where encode left it on the device —
-        # bucket padding and the copies to the device), the planner's
-        # "plen_check" and "dispatch_<mode>" (the launch) and "wait" (the
+        # "dispatch" is the device read: "upload" (bucket padding of the
+        # host arrays; device inputs are copied back first), the
+        # planner's "plen_check" (a host maximum) and "dispatch_<mode>"
+        # (the launch, which carries the batch's one host-to-device
+        # crossing: on the chip, host arrays handed to the jitted
+        # executor cost less than a device_put before it) and "wait" (the
         # device's work and the copy back, forced by _scan_tiers' first
         # host conversions); "merge" below is pure host-side reduction
         with tr.span("dispatch"):
             with tr.span("upload"):
+                if isinstance(patt, jax.Array) or isinstance(plen, jax.Array):
+                    self.planner.stats.input_copybacks += 1
                 patt_np, plen_np = np.asarray(patt), np.asarray(plen)
                 bucket = 1 << (B - 1).bit_length() if B > 1 else 1
                 if bucket != B:
@@ -773,10 +777,8 @@ class SuffixTable:
                         [patt_np, np.repeat(patt_np[:1], reps, axis=0)])
                     plen_np = np.concatenate(
                         [plen_np, np.repeat(plen_np[:1], reps)])
-                patt_dev = jnp.asarray(patt_np)
-                plen_dev = jnp.asarray(plen_np)
             count, base_rank, base_count, tiers = self._scan_tiers(
-                patt_dev, plen_dev, n_real=B)
+                patt_np, plen_np, n_real=B)
         with tr.span("merge"):
             delta = self._delta(tiers, plen_np, B)
             first_pos = self._base_min_positions(base_count, base_rank)

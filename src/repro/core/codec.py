@@ -35,6 +35,44 @@ def encode_dna(text: str | bytes | np.ndarray) -> np.ndarray:
     return codes
 
 
+def encode_pattern_batch(patterns, max_len: int, *, packed: bool):
+    """Pattern strings -> host numpy ``(rows, lengths)`` in one pass.
+
+    ``rows`` is ``(B, packed_length(max_len))`` uint32 words (the layout
+    of :func:`pack_2bit_batch`) when ``packed``, else ``(B, max_len)``
+    int32 codes, zero-padded; ``lengths`` is ``(B,)`` int32.  Upper and
+    lower case are accepted.  The strings are joined and decoded with
+    one table lookup and one masked scatter: no numpy call per pattern,
+    no device array.  Raises ``ValueError`` on a pattern longer than
+    ``max_len`` (compares are depth-capped, so it would match on its
+    truncated prefix) and on a non-ACGT symbol, in that order."""
+    B = len(patterns)
+    lengths = np.fromiter(map(len, patterns), np.int32, count=B)
+    if B and int(lengths.max()) > max_len:
+        p = patterns[int(np.argmax(lengths > max_len))]
+        raise ValueError(
+            f"pattern of length {len(p)} exceeds max_pattern_len="
+            f"{max_len} ({p[:32]!r}...); compares are depth-capped, so "
+            f"it would be silently truncated")
+    try:
+        text = np.frombuffer("".join(patterns).encode("ascii"), np.uint8)
+    except UnicodeEncodeError:
+        for p in patterns:          # the first offending pattern's error
+            p.encode("ascii")
+        raise
+    codes = _ASCII_TO_CODE[text]
+    if np.any(codes == 255):
+        bad = chr(int(text[np.argmax(codes == 255)]))
+        raise ValueError(f"non-DNA symbol {bad!r} in input")
+    width = (packed_length(max_len) * BASES_PER_WORD if packed
+             else max_len)
+    rows = np.zeros((B, width), np.uint8)
+    rows[np.arange(width)[None, :] < lengths[:, None]] = codes
+    if packed:
+        return pack_2bit_batch(rows), lengths
+    return rows.astype(np.int32), lengths
+
+
 def decode_dna(codes: np.ndarray) -> str:
     return "".join(DNA_ALPHABET[int(c)] for c in np.asarray(codes))
 

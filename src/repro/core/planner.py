@@ -106,6 +106,10 @@ class PlannerStats:
     base_only_batches: int = 0
     tier_reads: dict = dataclasses.field(
         default_factory=lambda: {"base": 0, "runs": 0, "memtable": 0})
+    # batches whose patterns arrived as device arrays and had to be
+    # copied back to the host to pad or check them (host-encoded
+    # batches, what ``encode`` returns, cross to the device once)
+    input_copybacks: int = 0
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -437,8 +441,11 @@ class ScanPlanner:
         if n_real is not None and not 0 <= n_real <= B:
             raise ValueError(f"n_real={n_real} out of range for batch {B}")
         if B:
-            # a leaf span: a device ``plen`` is copied back to the host
+            # a leaf span: the maximum of a host ``plen``; a device one
+            # from a caller that did not use ``encode`` is copied back
             with self.tracer.span("plen_check"):
+                if isinstance(plen, jax.Array):
+                    self.stats.input_copybacks += 1
                 max_plen = int(np.max(np.asarray(plen)))
             if max_plen > self.max_pattern_len:
                 raise ValueError(
@@ -475,9 +482,18 @@ class ScanPlanner:
         to ``queries`` (and record the batch under ``bucketed_batches``
         / ``pad_slots``); execution is unchanged — padding rows still
         run, which is the point of bucketing.
+
+        ``patt``/``plen`` may be host numpy arrays (what :meth:`encode`
+        returns; they cross to the device in the launch) or device
+        arrays.
         """
         B = int(patt.shape[0])
         self._check_plen(plen, B, n_real)
+        return self._scan_checked(patt, plen, B, mode, retry, n_real)
+
+    def _scan_checked(self, patt, plen, B: int, mode: Optional[str],
+                      retry: bool, n_real: Optional[int]) -> MatchResult:
+        """:meth:`scan_encoded` after its length check."""
         chosen = mode or self.plan(B).mode
         if chosen not in (MODE_SINGLE, MODE_BROADCAST, MODE_ROUTED,
                           MODE_FM):
@@ -573,9 +589,9 @@ class ScanPlanner:
                     self.store, tierset.stack, patt, plen)
         else:
             # mesh base scan keeps its own dispatch (and sentinel
-            # retries); scan_encoded does the accounting for it
-            base = self.scan_encoded(patt, plen, mode=chosen, retry=retry,
-                                     n_real=n_real)
+            # retries) and does the accounting for it; the lengths are
+            # checked above
+            base = self._scan_checked(patt, plen, B, chosen, retry, n_real)
             with self.tracer.span("dispatch_fused"):
                 tiers = ops.fused_tiers(tierset.stack, patt, plen)
             from repro.kernels.tier_scan import merge_tier_results
@@ -650,7 +666,9 @@ class ScanPlanner:
 
     # -- string-level API with LRU cache ------------------------------------
     def encode(self, patterns: list[str]):
-        """Encode pattern strings for :meth:`scan_encoded`: (patt, plen).
+        """Encode pattern strings for :meth:`scan_encoded`: (patt, plen)
+        as host numpy arrays — the batch crosses to the device once, where
+        it is dispatched.
 
         Packed uint32 words for DNA stores (word-packing rounds the width
         up to a 16-base multiple), exact-width int32 codes otherwise.
@@ -658,20 +676,8 @@ class ScanPlanner:
         are depth-capped, so a longer pattern would silently match on its
         truncated prefix.
         """
-        for p in patterns:
-            if len(p) > self.max_pattern_len:
-                raise ValueError(
-                    f"pattern of length {len(p)} exceeds max_pattern_len="
-                    f"{self.max_pattern_len} ({p[:32]!r}...); compares are "
-                    f"depth-capped, so it would be silently truncated")
-        if self.store.is_dna:
-            width = (codec.packed_length(self.max_pattern_len)
-                     * codec.BASES_PER_WORD)
-            _codes, packed, lengths = Q.encode_patterns(patterns, width)
-            return packed, lengths
-        codes, _packed, lengths = Q.encode_patterns(patterns,
-                                                    self.max_pattern_len)
-        return codes, lengths
+        return codec.encode_pattern_batch(patterns, self.max_pattern_len,
+                                          packed=self.store.is_dna)
 
     # back-compat alias (pre-api_redesign name)
     _encode = encode

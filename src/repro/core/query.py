@@ -50,24 +50,14 @@ class MatchResult:
 # ---------------------------------------------------------------------------
 def encode_patterns(patterns: list[str], max_len: int):
     """list of DNA strings -> (codes (B, max_len) int32 zero-padded,
-    packed (B, W) uint32, lengths (B,) int32)."""
-    B = len(patterns)
-    lengths = np.array([len(p) for p in patterns], np.int32)
-    assert lengths.max(initial=0) <= max_len, (
-        f"pattern length {int(lengths.max(initial=0))} exceeds "
-        f"max_len={max_len}")
-    W = codec.packed_length(max_len)
-    if B == 0:
-        # empty batches occur naturally (e.g. a retry pass with nothing to
-        # retry, or a fully cache-served planner batch) — np.stack([]) raises
-        return (jnp.zeros((0, max_len), jnp.int32),
-                jnp.zeros((0, W), jnp.uint32),
-                jnp.zeros((0,), jnp.int32))
-    codes = np.zeros((B, max_len), np.int32)
-    for i, p in enumerate(patterns):
-        codes[i, : len(p)] = codec.encode_dna(p)
+    packed (B, W) uint32, lengths (B,) int32), as device arrays.  A thin
+    wrapper over :func:`repro.core.codec.encode_pattern_batch`, whose
+    host arrays the table's read path uses instead; raises its
+    ``ValueError``s."""
+    codes, lengths = codec.encode_pattern_batch(patterns, max_len,
+                                                packed=False)
     packed = codec.pack_2bit_batch(codes)
-    return jnp.asarray(codes), jnp.asarray(packed[:, :W]), jnp.asarray(lengths)
+    return jnp.asarray(codes), jnp.asarray(packed), jnp.asarray(lengths)
 
 
 def random_patterns(num: int, min_len: int = 1, max_len: int = 100,

@@ -74,3 +74,16 @@ def test_refuses_to_run_without_a_tpu():
         capture_output=True, text=True, env=env, timeout=300, cwd=REPO)
     assert proc.returncode == 2, proc.stderr[-2000:]
     assert "no TPU" in proc.stderr
+
+
+def test_planner_counters_after_and_over_the_window():
+    before = {"batches": 3, "queries": 40, "input_copybacks": 0,
+              "mode_counts": {"single": 3}}
+    after = {"batches": 10, "queries": 200, "input_copybacks": 0,
+             "mode_counts": {"single": 10}}
+    got = idle_by_span.planner_counters(before, after)
+    assert got == {
+        "total": {"batches": 10, "queries": 200, "input_copybacks": 0},
+        "window": {"batches": 7, "queries": 160, "input_copybacks": 0}}
+    assert idle_by_span.planner_counters({}, {}) == {"total": {},
+                                                     "window": {}}

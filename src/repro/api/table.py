@@ -55,15 +55,15 @@ from repro.api.wal import WriteAheadLog
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import codec
 from repro.core.build_pipeline import BuildStats, chunk_rows_for_budget, \
-    in_memory_build_stats, staged_suffix_array
+    distributed_build_stats, in_memory_build_stats, staged_suffix_array
+from repro.core.dsa import build_suffix_array_distributed
 from repro.core.planner import ScanOutcome, ScanPlanner, TopKCache
 from repro.core.query import MatchResult
 from repro.core.rangemin import range_min, range_smallest
 from repro.serving.metrics import MetricsEmitter, table_record
 from repro.serving.trace import Tracer
 from repro.core.suffix_array import build_suffix_array
-from repro.core.tablet import TabletStore, build_tablet_store, \
-    place_on_mesh, store_from_arrays
+from repro.core.tablet import TabletStore, place_on_mesh, store_from_arrays
 from repro.launch.mesh import make_tablet_mesh
 
 # no leading dot: forbids '.', '..' (path traversal — drop_table rmtree's
@@ -131,7 +131,7 @@ class SuffixTable:
                  fm_threshold: Optional[int] = None,
                  _store: Optional[TabletStore] = None,
                  _planner: Optional[ScanPlanner] = None,
-                 _fm=None):
+                 _fm=None, _tracer: Optional[Tracer] = None):
         self.name = name
         self.root = root
         self.version = int(version)
@@ -148,8 +148,9 @@ class SuffixTable:
         self.runs: list[Run] = []
         self._codes = np.asarray(codes)
         # span histograms (stats()["latency"]): created before the
-        # planner so freeze/compaction rebinds keep one shared tracer
-        self.tracer = Tracer("table")
+        # planner so freeze/compaction rebinds keep one shared tracer;
+        # create() passes the one its build spans went to
+        self.tracer = _tracer if _tracer is not None else Tracer("table")
         self._metrics: Optional[MetricsEmitter] = None
 
         if _store is not None:                       # from_store: adopt as-is
@@ -206,12 +207,11 @@ class SuffixTable:
         """In-memory table (no persistence): build over ``codes`` now,
         distributed over the local mesh when >1 device is visible."""
         codes, is_dna = _as_codes(codes, is_dna)
-        t0 = time.perf_counter()
-        sa = cls._build_sa_for(codes, max_query_len, is_dna)
+        tracer = Tracer("table")
+        sa, build = cls._build_sa_for(codes, tracer)
         table = cls(codes, sa, is_dna=is_dna,
-                    max_query_len=max_query_len, **kw)
-        table._build = in_memory_build_stats(
-            len(codes), time.perf_counter() - t0)
+                    max_query_len=max_query_len, _tracer=tracer, **kw)
+        table._build = build
         table._maybe_freeze()
         return table
 
@@ -288,12 +288,11 @@ class SuffixTable:
                 max_device_bytes=max_device_bytes, spill_dir=spill_dir,
                 build_chunk_rows=build_chunk_rows, shard_rows=shard_rows,
                 **kw)
-        t0 = time.perf_counter()
-        sa = cls._build_sa_for(codes, max_query_len, is_dna)
+        tracer = Tracer("table")
+        sa, build = cls._build_sa_for(codes, tracer)
         table = cls(codes, sa, is_dna=is_dna, max_query_len=max_query_len,
-                    name=name, root=root, version=1, **kw)
-        table._build = in_memory_build_stats(
-            len(codes), time.perf_counter() - t0)
+                    name=name, root=root, version=1, _tracer=tracer, **kw)
+        table._build = build
         catalog.register(name, {"is_dna": table.is_dna,
                                 "max_query_len": table.max_query_len})
         table._persist()
@@ -403,18 +402,23 @@ class SuffixTable:
         return table
 
     @staticmethod
-    def _build_sa_for(codes: np.ndarray, max_query_len: int,
-                      is_dna: bool) -> np.ndarray:
-        """Real-row SA over ``codes`` — distributed over the local mesh
-        when >1 device is visible, single-device otherwise."""
+    def _build_sa_for(codes: np.ndarray, tracer: Optional[Tracer] = None
+                      ) -> tuple[np.ndarray, BuildStats]:
+        """Real-row SA over ``codes`` and what building it took —
+        distributed over the local mesh when >1 device is visible (its
+        compile and rounds timed as ``tracer``'s spans), single-device
+        otherwise."""
+        t0 = time.perf_counter()
         n_dev = len(jax.devices())
         if n_dev > 1:
-            mesh = make_tablet_mesh(n_dev)
-            store = build_tablet_store(codes, is_dna=is_dna,
-                                       max_query_len=max_query_len,
-                                       mesh=mesh, axis_name="tablets")
-            return np.asarray(store.sa)[store.pad_count:]
-        return np.asarray(build_suffix_array(codes.astype(np.int32)))
+            sa, pad, run = build_suffix_array_distributed(
+                codes, make_tablet_mesh(n_dev), "tablets", tracer=tracer)
+            sa = np.asarray(sa)[pad:]
+            return sa, distributed_build_stats(
+                len(codes), time.perf_counter() - t0, run)
+        sa = np.asarray(build_suffix_array(codes.astype(np.int32)))
+        return sa, in_memory_build_stats(len(codes),
+                                         time.perf_counter() - t0)
 
     def _attach(self, codes: np.ndarray, sa_real: np.ndarray) -> None:
         """(Re)build the runtime store for the current mesh.  An existing
@@ -519,11 +523,12 @@ class SuffixTable:
           / ``tier_reads`` (docs/read_path.md).  (True cross-caller
           coalescing counters live in ``Database.stats()["scheduler"]``.)
         * ``build`` — how the base index was constructed (``None`` for
-          adopted stores): ``mode`` (``"staged"``/``"in_memory"``),
-          ``rounds``, ``n_chunks``, ``chunk_rows``,
+          adopted stores): ``mode`` (``"staged"``/``"in_memory"``/
+          ``"distributed"``), ``rounds``, ``n_chunks``, ``chunk_rows``,
           ``peak_device_bytes``, ``spill_bytes``, ``elapsed_s``,
-          ``bases_per_s`` — the :class:`~repro.core.build_pipeline.
-          BuildStats` schema, persisted with the table
+          ``bases_per_s``, ``compile_s``, ``devices`` — the
+          :class:`~repro.core.build_pipeline.BuildStats` schema,
+          persisted with the table
           (docs/build_pipeline.md);
         * ``latency`` — rolling span histograms from the table's
           :class:`~repro.serving.trace.Tracer` (``encode`` /
@@ -1101,8 +1106,7 @@ class SuffixTable:
                 combined, self.n_base, base_sa,
                 is_dna=self.is_dna, max_query_len=self.max_query_len)
         elif self.mesh is not None and self._distributed_build:
-            sa_real = self.__class__._build_sa_for(
-                combined, self.max_query_len, self.is_dna)
+            sa_real, _ = self.__class__._build_sa_for(combined)
         else:
             pad = self.store.pad_count
             sa_real = merge_delta_sa(

@@ -70,7 +70,7 @@ def chunk_rows_for_budget(max_device_bytes: Optional[int]) -> int:
 class BuildStats:
     """Construction telemetry — surfaced as ``SuffixTable.stats()["build"]``."""
 
-    mode: str = "staged"            # "staged" | "in_memory"
+    mode: str = "staged"            # "staged" | "in_memory" | "distributed"
     n_bases: int = 0
     rounds: int = 0                 # sort/merge rounds actually run
     n_chunks: int = 0               # device chunks per round
@@ -79,6 +79,8 @@ class BuildStats:
     spill_bytes: int = 0            # cumulative bytes written to spill_dir
     elapsed_s: float = 0.0
     bases_per_s: float = 0.0
+    compile_s: float = 0.0          # lowering + compiling the build program
+    devices: int = 1                # devices the build ran over
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -97,6 +99,20 @@ def in_memory_build_stats(n: int, elapsed_s: float) -> BuildStats:
         chunk_rows=n, peak_device_bytes=n * BYTES_PER_ROW, spill_bytes=0,
         elapsed_s=elapsed_s,
         bases_per_s=(n / elapsed_s) if elapsed_s > 0 else 0.0)
+
+
+def distributed_build_stats(n: int, elapsed_s: float, build
+                            ) -> BuildStats:
+    """The schema for the mesh build (``core/dsa.py``): ``build`` is its
+    ``DistributedBuild``, which counted the rounds the program ran and
+    timed its compile."""
+    m = -(-n // build.devices)
+    return BuildStats(
+        mode="distributed", n_bases=n, rounds=build.rounds, n_chunks=1,
+        chunk_rows=m, peak_device_bytes=m * BYTES_PER_ROW, spill_bytes=0,
+        elapsed_s=elapsed_s,
+        bases_per_s=(n / elapsed_s) if elapsed_s > 0 else 0.0,
+        compile_s=build.compile_s, devices=build.devices)
 
 
 # --------------------------------------------------------------------------

@@ -110,6 +110,11 @@ class PlannerStats:
     # copied back to the host to pad or check them (host-encoded
     # batches, what ``encode`` returns, cross to the device once)
     input_copybacks: int = 0
+    # tablet-level searches of the real queries: p per query for a
+    # broadcast batch (every tablet searches it), 1 for a routed or
+    # single-device one, plus the exact mode's count per retried row —
+    # the work the mesh multiplies (``tablet_searches / queries``)
+    tablet_searches: int = 0
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -258,10 +263,11 @@ class ScanPlanner:
         save bandwidth but overflow hot tablets more often — overflow is
         corrected by the retry pass, trading latency for exactness.
     routed_min_batch:
-        Batches at least this large prefer the routed path (per-device
-        work O(B/p log m) instead of O(B log m)); smaller batches
-        broadcast.  The routed path also requires a DNA store and a batch
-        divisible into the mesh (the planner pads internally).
+        Batches of at least this many real queries prefer the routed
+        path (per-device work O(B/p log m) instead of O(B log m));
+        smaller batches broadcast, whatever bucket their padding fills.
+        The routed path also requires a DNA store and a batch divisible
+        into the mesh (the planner pads internally).
     cache_size:
         LRU entries for the string-level API (0 disables caching).
     """
@@ -297,6 +303,7 @@ class ScanPlanner:
         # executors are built lazily and injectable for tests: each maps
         # (patt, plen) -> MatchResult
         self._executors: dict[str, Callable] = {}
+        self._compiled_ahead: set[int] = set()   # routed batch sizes
 
     def rebind(self, store: TabletStore, *, fm=None) -> None:
         """Swap the underlying store in place (major compaction publishes
@@ -323,6 +330,7 @@ class ScanPlanner:
         self.store = store
         self.max_pattern_len = int(store.max_query_len)
         self._executors.clear()
+        self._compiled_ahead.clear()
         self._sa_host = None
         self._sa_bmin = None
         self._cache.bump()
@@ -342,7 +350,8 @@ class ScanPlanner:
         return int(np.prod([self.mesh.shape[a] for a in self.mesh.axis_names]))
 
     def plan(self, batch: int) -> ScanPlan:
-        """Pick the executor for a batch of ``batch`` queries."""
+        """Pick the executor for a batch of ``batch`` queries (the real
+        ones: a bucketed batch is planned by its ``n_real``)."""
         if self.fm is not None:
             return ScanPlan(MODE_FM,
                             "frozen table: FM backward search", batch)
@@ -393,7 +402,12 @@ class ScanPlanner:
             def broadcast(sa_local, meta, patt, plen):
                 return Q.query_sharded(sa_local, meta, patt, plen, ax)
 
-            return lambda patt, plen: broadcast(store.sa, meta, patt, plen)
+            def run(patt, plen):
+                return broadcast(store.sa, meta, patt, plen)
+
+            run.compile_ahead = lambda patt, plen: broadcast.lower(
+                store.sa, meta, patt, plen).compile()
+            return run
 
         if mode == MODE_ROUTED:
             cf = self.capacity_factor
@@ -465,6 +479,9 @@ class ScanPlanner:
             self.stats.bucketed_queries += n_real
             self.stats.pad_slots += B - n_real
         self.stats.mode_counts[chosen] += 1
+        fan_out = self.num_tablets if chosen == MODE_BROADCAST else 1
+        self.stats.tablet_searches += fan_out * (B if n_real is None
+                                                 else n_real)
 
     def scan_encoded(self, patt, plen, *, mode: Optional[str] = None,
                      retry: bool = True,
@@ -494,7 +511,7 @@ class ScanPlanner:
     def _scan_checked(self, patt, plen, B: int, mode: Optional[str],
                       retry: bool, n_real: Optional[int]) -> MatchResult:
         """:meth:`scan_encoded` after its length check."""
-        chosen = mode or self.plan(B).mode
+        chosen = mode or self.plan(B if n_real is None else n_real).mode
         if chosen not in (MODE_SINGLE, MODE_BROADCAST, MODE_ROUTED,
                           MODE_FM):
             raise ValueError(f"unknown scan mode {chosen!r}")
@@ -508,6 +525,15 @@ class ScanPlanner:
             z = jnp.zeros((0,), jnp.int32)
             return MatchResult(found=z.astype(bool), count=z,
                                first_rank=z, first_pos=z)
+        if chosen == MODE_ROUTED and B not in self._compiled_ahead:
+            # a bucket that reaches routed_min_batch also holds fewer real
+            # queries, which broadcast: compile that program for it now,
+            # where the routed one first runs, not on first use
+            self._compiled_ahead.add(B)
+            ahead = getattr(self._executor(self._exact_mode()),
+                            "compile_ahead", None)
+            if ahead is not None:
+                ahead(patt, plen)
         # NOTE jax dispatch is async: this span is the launch (enqueue +
         # any host work the executor does); the table's "wait" span pays
         # the device's work when it first forces the result
@@ -518,14 +544,21 @@ class ScanPlanner:
 
         count = np.asarray(res.count)
         # retry negative sentinels, plus any row claiming a match without a
-        # usable rank (defensive: rank feeds locate()'s SA-slice gather)
+        # usable rank (defensive: rank feeds locate()'s SA-slice gather);
+        # bucket padding rows (past ``n_real``) are discarded by the
+        # caller, so they are never retried
         rank_bad = (count > 0) & (np.asarray(res.first_rank) < 0)
+        if n_real is not None:
+            rank_bad[n_real:] = False
+            count = count.copy()
+            count[n_real:] = np.maximum(count[n_real:], 0)
         bad = np.flatnonzero((count < 0) | rank_bad)
         if bad.size == 0:
             return res
         self.stats.retried_overflow += int((count[bad] == -1).sum())
         self.stats.retried_saturated += int((count[bad] == -2).sum())
         self.stats.retried_inexact_rank += int(rank_bad.sum())
+        self.stats.tablet_searches += int(bad.size) * self.num_tablets
         # pad the retry batch to a power-of-two bucket: its size varies
         # per batch, and the jitted exact executor recompiles per shape —
         # bucketing bounds that to log2(B) compilations
@@ -536,17 +569,15 @@ class ScanPlanner:
         sub = self._executor(self._exact_mode())(
             jnp.asarray(np.asarray(patt)[take]),
             jnp.asarray(np.asarray(plen)[take]))
-        sub = MatchResult(found=sub.found[:n_bad], count=sub.count[:n_bad],
-                          first_rank=sub.first_rank[:n_bad],
-                          first_pos=sub.first_pos[:n_bad])
         found = np.asarray(res.found).copy()
         first_rank = np.asarray(res.first_rank).copy()
         first_pos = np.asarray(res.first_pos).copy()
         count = count.copy()
-        found[bad] = np.asarray(sub.found)
-        count[bad] = np.asarray(sub.count)
-        first_rank[bad] = np.asarray(sub.first_rank)
-        first_pos[bad] = np.asarray(sub.first_pos)
+        # sliced on the host: a device slice would compile once per n_bad
+        found[bad] = np.asarray(sub.found)[:n_bad]
+        count[bad] = np.asarray(sub.count)[:n_bad]
+        first_rank[bad] = np.asarray(sub.first_rank)[:n_bad]
+        first_pos[bad] = np.asarray(sub.first_pos)[:n_bad]
         return MatchResult(found=jnp.asarray(found), count=jnp.asarray(count),
                            first_rank=jnp.asarray(first_rank),
                            first_pos=jnp.asarray(first_pos))
@@ -578,7 +609,7 @@ class ScanPlanner:
             return res, None
         self._check_plen(plen, B, n_real)
         n_runs = sum(1 for k in tierset.kinds if k == "run")
-        chosen = mode or self.plan(B).mode
+        chosen = mode or self.plan(B if n_real is None else n_real).mode
         from repro.kernels import ops
 
         if chosen == MODE_SINGLE:

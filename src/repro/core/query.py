@@ -225,17 +225,19 @@ def query_sharded(sa_local: jnp.ndarray, store_meta: TabletStore,
     d = lax.axis_index(axis_name)
     B = patt.shape[0]
 
-    local_lb = _bounded_search(
-        sa_local, lambda pos: _compare(store_meta, pos, patt, plen)[0], B, m,
-        varying_axis=axis_name)
-    local_ub = _bounded_search(
-        sa_local,
-        lambda pos: (lambda lt, eq: lt | eq)(
-            *_compare(store_meta, pos, patt, plen)), B, m,
-        varying_axis=axis_name)
+    # both bounds in one search over 2B slots: half the loop iterations
+    # (and launched operations) of two searches, each as wide as both
+    upper = lax.broadcasted_iota(jnp.int32, (2 * B,), 0) >= B
+    patt2 = lax.concatenate([patt, patt], 0)
+    plen2 = lax.concatenate([plen, plen], 0)
 
-    lb = lax.psum(local_lb, axis_name)
-    ub = lax.psum(local_ub, axis_name)
+    def before(pos):
+        lt, eq = _compare(store_meta, pos, patt2, plen2)
+        return lt | (upper & eq)
+
+    bounds = lax.psum(_bounded_search(sa_local, before, 2 * B, m,
+                                      varying_axis=axis_name), axis_name)
+    lb, ub = lax.slice(bounds, (0,), (B,)), lax.slice(bounds, (B,), (2 * B,))
     count = ub - lb
     found = count > 0
     # tablet owning the global lower bound: lb in [d*m, (d+1)*m)

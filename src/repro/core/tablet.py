@@ -275,7 +275,7 @@ def build_tablet_store(codes, *, is_dna: bool | None = None,
                                  num_tablets=num_tablets,
                                  min_rows=min_rows)
     assert axis_name is not None
-    sa, _pad = build_suffix_array_distributed(codes, mesh, axis_name,
-                                              method=method)
+    sa, _pad, _ = build_suffix_array_distributed(codes, mesh, axis_name,
+                                                 method=method)
     return _finalize_store(codes, sa, int(sa.shape[0]),
                            is_dna=bool(is_dna), max_query_len=max_query_len)

@@ -202,7 +202,7 @@ def lower_sa_build(mesh, method="bitonic"):
     def build(codes_local):
         return build_suffix_array_sharded(
             codes_local, n_real=SA.text_len, axis_name="tablets",
-            method=method, num_steps=1)
+            method=method, num_steps=1)[:2]
 
     jitted = jax.jit(build)
     with jax.set_mesh(tmesh):

@@ -158,7 +158,7 @@ def test_staged_create_bit_identical_and_stats(tmp_path):
     assert b["mode"] == "staged" and b["spill_bytes"] > 0
     assert set(b) == {"mode", "n_bases", "rounds", "n_chunks", "chunk_rows",
                       "peak_device_bytes", "spill_bytes", "elapsed_s",
-                      "bases_per_s"}
+                      "bases_per_s", "compile_s", "devices"}
     assert b["bases_per_s"] > 0
     # the snapshot on disk is the streamed-shard kind
     mgr = CheckpointManager(str(tmp_path / "g"))

@@ -377,7 +377,8 @@ def test_stats_schema_is_stable_and_documented():
                                           "n", "total", "sum_ms"}
     assert set(s["build"]) == {"mode", "n_bases", "rounds", "n_chunks",
                                "chunk_rows", "peak_device_bytes",
-                               "spill_bytes", "elapsed_s", "bases_per_s"}
+                               "spill_bytes", "elapsed_s", "bases_per_s",
+                               "compile_s", "devices"}
     assert s["build"]["mode"] == "in_memory"    # from_codes: one sort
     assert s["build"]["n_bases"] == 800
     assert set(s["tiers"]) == {"base_rows", "run_count", "run_rows",
@@ -391,7 +392,8 @@ def test_stats_schema_is_stable_and_documented():
     assert s["tiers"]["base_rows"] == 800 and s["tiers"]["run_count"] == 1
     assert s["cache"]["hits"] >= 1 and s["cache"]["generation"] >= 1
     for key in ("batches", "queries", "bucketed_batches",
-                "bucketed_queries", "pad_slots", "mode_counts"):
+                "bucketed_queries", "pad_slots", "mode_counts",
+                "tablet_searches"):
         assert key in s["planner"], key
     dbs = db.stats()
     assert set(dbs) == {"scheduler", "tables"}

@@ -181,3 +181,80 @@ def test_token_corpus_goes_through_planner():
     assert int(res.count[0]) == 2
     pos = planner.positions_from_result(res, top_k=4)
     assert sorted(int(x) for x in pos[0] if x >= 0) == [1000, 2000]
+
+
+class _FourTablets:
+    """Stands in for a 4-device tablet mesh: the planner reads only its
+    shape to count tablets, and every executor here is injected."""
+    shape = {"tablets": 4}
+    axis_names = ("tablets",)
+
+
+@pytest.fixture()
+def mesh_planner():
+    """A planner over a 4-tablet store whose broadcast and routed
+    executors are the single-device search (routed stamps overflow
+    sentinels on the rows listed in ``overflow``)."""
+    store4 = build_tablet_store(codec.random_dna(TEXT_N, seed=0),
+                                is_dna=True, num_tablets=4)
+    planner = ScanPlanner(store4)
+    planner.mesh = _FourTablets()
+    real = planner._executor(MODE_SINGLE)
+    planner.overflow = []
+
+    def routed(patt, plen):
+        res = real(patt, plen)
+        count = np.asarray(res.count).copy()
+        count[planner.overflow] = -1
+        return MatchResult(found=jnp.asarray(count > 0),
+                           count=jnp.asarray(count),
+                           first_rank=res.first_rank,
+                           first_pos=res.first_pos)
+
+    planner._executors[MODE_BROADCAST] = real
+    planner._executors[MODE_ROUTED] = routed
+    return planner
+
+
+def _batch(n, seed):
+    _, pp, pl = Q.encode_patterns(Q.random_patterns(n, 1, 10, seed=seed),
+                                  112)
+    return pp, pl
+
+
+def test_tablet_searches_of_a_broadcast_batch_are_b_times_p(mesh_planner):
+    pp, pl = _batch(12, 21)
+    assert mesh_planner.num_tablets == 4
+    mesh_planner.scan_encoded(pp, pl)            # 12 < 64: broadcast
+    assert mesh_planner.stats.mode_counts[MODE_BROADCAST] == 1
+    assert mesh_planner.stats.tablet_searches == 12 * 4
+    # bucket padding slots are not queries and are not counted
+    mesh_planner.scan_encoded(pp, pl, n_real=9)
+    assert mesh_planner.stats.tablet_searches == 12 * 4 + 9 * 4
+    assert mesh_planner.stats.as_dict()["tablet_searches"] == 84
+
+
+def test_a_bucketed_batch_is_planned_by_its_real_queries(mesh_planner):
+    pp, pl = _batch(64, 24)
+    mesh_planner.scan_encoded(pp, pl, n_real=40)   # 64 slots, 40 real
+    mesh_planner.scan_encoded(pp, pl, n_real=64)
+    assert mesh_planner.stats.mode_counts[MODE_BROADCAST] == 1
+    assert mesh_planner.stats.mode_counts[MODE_ROUTED] == 1
+    assert mesh_planner.stats.tablet_searches == 40 * 4 + 64
+
+
+def test_tablet_searches_of_a_routed_batch_are_b(mesh_planner):
+    pp, pl = _batch(64, 22)
+    mesh_planner.scan_encoded(pp, pl)            # 64 >= 64: routed
+    assert mesh_planner.stats.mode_counts[MODE_ROUTED] == 1
+    assert mesh_planner.stats.retried_overflow == 0
+    assert mesh_planner.stats.tablet_searches == 64
+
+
+def test_tablet_searches_count_p_per_retried_row(mesh_planner):
+    pp, pl = _batch(64, 23)
+    mesh_planner.overflow = [0, 5, 17, 63]
+    res = mesh_planner.scan_encoded(pp, pl, mode=MODE_ROUTED)
+    assert (np.asarray(res.count) >= 0).all()
+    assert mesh_planner.stats.retried_overflow == 4
+    assert mesh_planner.stats.tablet_searches == 64 + 4 * 4

@@ -114,3 +114,25 @@ def test_fm_read_compiles_at_250m(shape):
         is_dna=True, sample_rate=32, vocab=4)
     _compile(ops.fm_search, arrays, *_patterns(shape))
 
+
+
+def test_looped_distributed_build_compiles_for_four_chips(topo):
+    """The four-tablet build (``core/dsa.py``): one ``while`` loop whose
+    shift picks its blocks with a ``lax.switch`` of ``ppermute``s, which
+    the TPU compiler must accept inside the loop."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.dsa import build_suffix_array_sharded
+    mesh = Mesh(np.array(topo.devices[:4]), ("tablets",))
+    n = 1 << 12          # the program is the same at any length
+    build = jax.jit(jax.shard_map(
+        functools.partial(build_suffix_array_sharded, n_real=n - 3,
+                          axis_name="tablets"),
+        mesh=mesh, in_specs=(P("tablets"),),
+        out_specs=(P("tablets"), P("tablets"), P())))
+    text = _compile(build, jax.ShapeDtypeStruct(
+        (n,), jnp.int32, sharding=NamedSharding(mesh, P("tablets"))))
+    assert "collective-permute" in text and "while" in text

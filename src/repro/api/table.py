@@ -662,7 +662,8 @@ class SuffixTable:
         """One fused merged dispatch and the wait for its result, as host
         arrays of the first ``n_real`` rows: (merged count, base-SA
         ``first_rank``, base-only count, the delta tiers' ``(less,
-        matches)`` | None — see :meth:`_delta`)."""
+        matches)`` | None — see :meth:`_delta` —, the frozen base's
+        ``text_min`` | None — see :meth:`_base_min_positions`)."""
         merged, tres = self.planner.scan_tiers(
             self._tierset(), patt, plen, mode=mode, n_real=n_real)
         B = len(plen) if n_real is None else int(n_real)
@@ -671,12 +672,13 @@ class SuffixTable:
         with self.tracer.span("wait"):
             count = np.asarray(merged.count).astype(np.int64)[:B]
             base_rank = np.asarray(merged.first_rank)[:B]
+            text_min = self.planner.host_text_min(merged, B)
             if tres is None:
-                return count, base_rank, count, None
+                return count, base_rank, count, None, text_min
             tcount = np.asarray(tres.count)
             tiers = (np.asarray(tres.less), np.asarray(tres.matches))
         base_count = count - tcount[:, :B].astype(np.int64).sum(axis=0)
-        return count, base_rank, base_count, tiers
+        return count, base_rank, base_count, tiers, text_min
 
     def _delta(self, tiers, plen, B: int):
         """Per query, the ascending delta-tier positions (None without
@@ -695,15 +697,24 @@ class SuffixTable:
         return sa.__getitem__, self.planner._sa_block_min(), \
             self.store.pad_count
 
-    def _base_min_positions(self, base_count, base_rank) -> np.ndarray:
+    def _base_min_positions(self, base_count, base_rank,
+                            text_min=None) -> np.ndarray:
         """Per query, the smallest BASE text position among its base-tier
         matches (-1 when none) — the text-order ``first_pos`` reduction
         over the rows ``[lb, lb + count)``, bounded by block minima
-        (``core.rangemin``) instead of gathering every match."""
+        (``core.rangemin``) instead of gathering every match.  On a
+        frozen table the FM launch already settled most queries
+        (``text_min``, :meth:`ScanPlanner.host_text_min`); only the rest
+        take the host's LF walks."""
         get, bmin, off = self._base_rows()
         count = np.where(base_rank >= 0, base_count, 0)
-        return range_min(get, bmin, off + base_rank.astype(np.int64),
-                         count)
+        if text_min is None:
+            return range_min(get, bmin, off + base_rank.astype(np.int64),
+                             count)
+        settled = text_min[1] != 0
+        rest = range_min(get, bmin, off + base_rank.astype(np.int64),
+                         np.where(settled, 0, count))
+        return np.where(settled, text_min[0].astype(np.int64), rest)
 
     def scan_encoded(self, patt, plen, *, mode: Optional[str] = None
                      ) -> MatchResult:
@@ -782,11 +793,12 @@ class SuffixTable:
                         [patt_np, np.repeat(patt_np[:1], reps, axis=0)])
                     plen_np = np.concatenate(
                         [plen_np, np.repeat(plen_np[:1], reps)])
-            count, base_rank, base_count, tiers = self._scan_tiers(
-                patt_np, plen_np, n_real=B)
+            count, base_rank, base_count, tiers, text_min = \
+                self._scan_tiers(patt_np, plen_np, n_real=B)
         with tr.span("merge"):
             delta = self._delta(tiers, plen_np, B)
-            first_pos = self._base_min_positions(base_count, base_rank)
+            first_pos = self._base_min_positions(base_count, base_rank,
+                                                 text_min)
             positions = (np.full((B, top_k), -1, np.int64)
                          if top_k else None)
             for i in range(B):
@@ -865,7 +877,7 @@ class SuffixTable:
         if limit is not None and limit <= 0:
             raise ValueError(f"limit must be positive, got {limit}")
         patt, plen = self.planner.encode([pattern])
-        _count, base_rank, base_count, tiers = self._scan_tiers(
+        _count, base_rank, base_count, tiers, _ = self._scan_tiers(
             patt, plen, n_real=1)
         run = self._base_slice(base_count, base_rank, 0)
         delta = self._delta(tiers, plen, 1)

@@ -56,7 +56,7 @@ from jax import shard_map
 
 from repro.core import codec
 from repro.core import query as Q
-from repro.core.query import MatchResult
+from repro.core.query import FMMatchResult, MatchResult
 from repro.core.rangemin import block_minima
 from repro.core.tablet import TabletStore
 from repro.serving.trace import Tracer
@@ -115,6 +115,10 @@ class PlannerStats:
     # single-device one, plus the exact mode's count per retried row —
     # the work the mesh multiplies (``tablet_searches / queries``)
     tablet_searches: int = 0
+    # real queries of frozen ``SuffixTable`` reads whose text-order
+    # smallest position the FM launch settled (``host_text_min``): the
+    # rest take the host LF walks (``SuffixTable._base_min_positions``)
+    device_min_settled: int = 0
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -634,6 +638,10 @@ class ScanPlanner:
                             first_pos=jnp.asarray(base.first_pos,
                                                   jnp.int32)),
                 tiers[0], tiers[3])
+            if isinstance(base, FMMatchResult):
+                # the frozen base's own text-order minimum rides along
+                merged = FMMatchResult(**vars(merged),
+                                       text_min=base.text_min)
         self.stats.fused_batches += 1
         self.stats.tier_reads["runs"] += n_runs
         self.stats.tier_reads["memtable"] += tierset.num_tiers - n_runs
@@ -642,6 +650,18 @@ class ScanPlanner:
         tres = TierScanResult(count=tiers[0], less=tiers[1],
                               matches=tiers[2], first_g=tiers[3])
         return merged, tres
+
+    def host_text_min(self, res: MatchResult,
+                      n_real: int) -> Optional[np.ndarray]:
+        """The frozen base's text-order minimum of a :meth:`scan_tiers`
+        result as a host ``(2, n_real)`` array (``FMMatchResult.
+        text_min``), its settled real queries counted in
+        ``device_min_settled``; None when another base answered."""
+        if not isinstance(res, FMMatchResult):
+            return None
+        text_min = np.asarray(res.text_min)[:, :n_real]
+        self.stats.device_min_settled += int(text_min[1].sum())
+        return text_min
 
     # -- match enumeration --------------------------------------------------
     def _sa(self) -> np.ndarray:

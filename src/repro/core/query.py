@@ -45,6 +45,19 @@ class MatchResult:
     first_pos: jnp.ndarray   # (B,)  int32   — text position of first match
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=("found", "count", "first_rank", "first_pos",
+                      "text_min"),
+         meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class FMMatchResult(MatchResult):
+    """A frozen-tier base read (``kernels.ops.fm_search``): the
+    MatchResult, plus the text-order minimum over each query's whole
+    match range where the launch settled it (``fm_scan.finish_match``)."""
+    text_min: jnp.ndarray    # (2, B) int32 — [0] smallest text position
+    #                          (-1 when none), [1] 1 where settled
+
+
 # ---------------------------------------------------------------------------
 # Pattern encoding
 # ---------------------------------------------------------------------------

@@ -221,16 +221,62 @@ def lf_walk(fa: FMArrays, rows):
     return pos
 
 
+def match_lanes(lo, hi, n):
+    """Lay a batch's match ranges ``[lo, hi)`` of ``SA$`` out on
+    ``4 * B`` walk lanes, B the batch's size: lane ``i < B`` holds range
+    ``i``'s first row ``lo``, and lanes ``[B, 4B)`` hold the rows
+    ``lo + 1 .. hi - 1`` of the ranges, in batch order.  A range is
+    *settled* when all of its rows fit; one longer than the ``3B`` extra
+    lanes alone is skipped, so it does not crowd out the ranges after
+    it.  The lanes are handed out in batch order: the first range that
+    fits the budget but not the lanes still free unsettles itself and
+    every later range with extra rows (a greedy skip would be a
+    sequential loop over B).  Empty ranges (and the ``$`` row's,
+    ``lo == 0``) are settled with nothing to walk.  Returns (rows (4B,) int32 clipped to ``[1, n]``,
+    each extra lane's range (3B,) int32 — B for an idle or unsettled
+    lane —, settled (B,) bool, has_rows (B,) bool)."""
+    B = lo.shape[0]
+    budget = 3 * B
+    has_rows = (hi > lo) & (lo >= 1)
+    extra = jnp.where(has_rows, hi - lo - 1, 0)
+    take = jnp.where(extra <= budget, extra, 0)
+    # inclusive running sum of the lanes taken, saturating above the
+    # budget so that no batch overflows int32
+    end = lax.associative_scan(
+        lambda a, b: jnp.minimum(a + b, budget + 1), take)
+    start = jnp.concatenate([jnp.zeros((1,), end.dtype), end[:-1]])
+    settled = (extra == 0) | ((extra <= budget) & (end <= budget))
+    k = jnp.arange(budget, dtype=jnp.int32)
+    owner = jnp.searchsorted(end, k, side="right").astype(jnp.int32)
+    oc = jnp.minimum(owner, B - 1)
+    rows = jnp.take(lo, oc) + 1 + k - jnp.take(start, oc)
+    owner = jnp.where((owner < B) & jnp.take(settled, oc), owner, B)
+    rows = jnp.clip(jnp.concatenate([lo, rows]), 1, n)
+    return rows, owner, settled, has_rows
+
+
 def finish_match(fa: FMArrays, lo, hi):
-    """(lo, hi) -> (found, count, first_rank, first_pos) int32, matching
-    the binary-search path's conventions exactly: ``first_rank`` is the
-    real-SA lower-bound row ``lo - 1`` when found and -1 otherwise;
-    ``first_pos`` is the matched run's first text position in suffix-rank
-    order (one LF walk), -1 when not found."""
+    """(lo, hi) -> (found, count, first_rank, first_pos, text_min),
+    matching the binary-search path's conventions exactly:
+    ``first_rank`` is the real-SA lower-bound row ``lo - 1`` when found
+    and -1 otherwise; ``first_pos`` is the matched run's first text
+    position in suffix-rank order, -1 when not found.
+
+    ``text_min`` (2, B) int32 is the text-order reduction over each
+    whole range: row 0 the smallest text position of its matches (-1
+    when none or not settled), row 1 is 1 where the range was settled
+    (:func:`match_lanes`).  One LF walk over all the lanes gives both:
+    lane ``i < B`` is ``first_pos``, the rest the range's other rows."""
+    B = lo.shape[0]
     count = hi - lo
     found = count > 0
     first_rank = jnp.where(found, lo - 1, -1)
-    pos = lf_walk(fa, jnp.clip(lo, 1, fa.n))
-    first_pos = jnp.where(found, pos, -1)
+    rows, owner, settled, has_rows = match_lanes(lo, hi, fa.n)
+    pos = lf_walk(fa, rows)
+    first_pos = jnp.where(found, pos[:B], -1)
+    rest = jnp.full((B,), jnp.iinfo(jnp.int32).max, jnp.int32).at[
+        owner].min(pos[B:], mode="drop")
+    low = jnp.where(settled & has_rows, jnp.minimum(pos[:B], rest), -1)
+    text_min = jnp.stack([low, settled.astype(jnp.int32)])
     return found, count.astype(jnp.int32), first_rank.astype(jnp.int32), \
-        first_pos.astype(jnp.int32)
+        first_pos.astype(jnp.int32), text_min.astype(jnp.int32)

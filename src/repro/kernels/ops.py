@@ -137,18 +137,18 @@ def fused_single(store, stack, patterns, plen):
 
 @jax.jit
 def fm_search(arrays, patterns, plen):
-    """Frozen-tier base read: FM backward search + one LF walk for
-    ``first_pos``, a single jitted launch.  Same MatchResult contract as
-    ``query`` with one widening: ``first_rank`` is the real-SA lower
-    bound for EVERY query (found or not) — ``merge_tier_results`` only
-    reads it through a ``count > 0`` guard, so the paths stay
-    bit-identical where it matters.  The search is XLA on every backend
-    (the index stays in HBM; a chip kernel is ROADMAP S5)."""
+    """Frozen-tier base read: FM backward search + one LF walk over every
+    hit of the batch (``fm_scan.finish_match``), a single jitted launch.
+    Same MatchResult contract as ``query``, plus ``text_min``: the
+    text-order minimum of each settled query's matches, so the table
+    walks on the host only the ranges the launch did not settle.  The
+    search is XLA on every backend (the index stays in HBM)."""
     if arrays.is_dna and patterns.dtype == jnp.uint32:
         syms = _fm.syms_from_packed(patterns, plen, patterns.shape[1] * 16)
     else:
         syms = _fm.syms_from_codes(patterns, plen, patterns.shape[1])
     lo, hi = _fm.search_syms(arrays, syms)
-    found, count, first_rank, first_pos = _fm.finish_match(arrays, lo, hi)
-    return _q.MatchResult(found=found, count=count,
-                          first_rank=first_rank, first_pos=first_pos)
+    found, count, first_rank, first_pos, text_min = _fm.finish_match(
+        arrays, lo, hi)
+    return _q.FMMatchResult(found=found, count=count, first_rank=first_rank,
+                            first_pos=first_pos, text_min=text_min)

@@ -283,3 +283,81 @@ assert out.count.tolist() == want['count']
 assert out.positions.tolist() == want['pos']
 print('OK')
 """, n_devices=8)
+
+
+# ---------------------------------------------------------------------------
+# the launch's text-order minimum (``text_min``) vs brute force and the
+# host LF walks it replaces
+# ---------------------------------------------------------------------------
+def _windows(text, rng, num, lo, hi):
+    starts = rng.integers(0, len(text) - hi, num)
+    return [text[s:s + int(rng.integers(lo, hi + 1))] for s in starts]
+
+
+def _text_min_case(kind):
+    """(text codes, patterns as the batch sends them, real rows)."""
+    rng = np.random.default_rng(31)
+    if kind == "repeats":
+        codes = codec.encode_dna("ACGT" * 120 + "A" * 160 + "ACGT" * 40)
+        text = codec.decode_dna(codes)
+        pats = ["ACGT" * 2, "AAAA", "CA", "ACGT" * 10, "A" * 40, "TA"]
+        return codes, pats + _windows(text, rng, 10, 4, 30), 16
+    codes = codec.random_dna(1500, seed=11)
+    text = codec.decode_dna(codes)
+    if kind == "random":
+        return codes, _windows(text, rng, 32, 4, 12), 32
+    if kind == "overflow":                      # 3B = 24 lanes, few fit
+        return codes, ["A", "C", "G", "T", "AC", "GT", "ACG", "TTA"], 8
+    if kind == "missing":
+        miss = [codec.decode_dna(codec.random_dna(20, seed=s))
+                for s in range(6)]
+        return codes, miss + _windows(text, rng, 2, 8, 12), 8
+    assert kind == "padded"                     # 5 real rows, row 0 x3
+    pats = _windows(text, rng, 5, 5, 9)
+    return codes, pats + [pats[0]] * 3, 5
+
+
+@pytest.mark.parametrize("kind", ["random", "repeats", "overflow",
+                                  "missing", "padded"])
+def test_fm_search_text_min_matches_brute_force_and_host_walks(kind):
+    from repro.core.rangemin import range_min
+    from repro.core.suffix_array import build_suffix_array
+    from repro.kernels import ops
+
+    codes, pats, n_real = _text_min_case(kind)
+    text = codec.decode_dna(codes)
+    fm = FMIndex.build(codes, is_dna=True)
+    patt, plen = codec.encode_pattern_batch(pats, 64, packed=True)
+    res = ops.fm_search(fm.arrays, patt, plen)
+    count = np.asarray(res.count).astype(np.int64)
+    rank = np.asarray(res.first_rank).astype(np.int64)
+    min_pos, settled = np.asarray(res.text_min)
+    settled = settled.astype(bool)
+
+    sa = np.asarray(build_suffix_array(codes))
+    brute = np.array([min((int(s) for s in sa if text.startswith(p, s)),
+                          default=-1) for p in pats])
+    host = range_min(fm.ranks_to_positions, fm.row_min, rank + 1,
+                     np.where(rank >= 0, count, 0))
+    assert np.array_equal(host, brute)
+    assert np.array_equal(min_pos[settled], brute[settled])
+    assert np.all(min_pos[~settled] == -1)
+    # settled = the range fits the 3B extra lanes, in batch order, a
+    # range longer than all of them skipped
+    budget, used, want = 3 * len(pats), 0, []
+    for c in count:
+        extra = max(int(c) - 1, 0)
+        if extra <= budget:
+            used += extra
+        want.append(extra == 0 or (extra <= budget and used <= budget))
+    assert np.array_equal(settled, want)
+    assert np.all(settled[count <= 1])
+    # the table's merge: the host walks only the unsettled rows
+    rest = range_min(fm.ranks_to_positions, fm.row_min, rank + 1,
+                     np.where(settled | (rank < 0), 0, count))
+    assert np.array_equal(np.where(settled, min_pos, rest)[:n_real],
+                          brute[:n_real])
+    if kind in ("repeats", "overflow"):       # both paths are exercised
+        assert settled.any() and not settled.all()
+    else:
+        assert settled[:n_real].all()

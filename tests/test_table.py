@@ -384,3 +384,40 @@ def test_rangemin_matches_brute_force(n, block, k):
             range_smallest(vals.__getitem__, bmin, lo[i], count[i], k,
                            block),
             np.sort(seg)[:k])
+
+
+@pytest.mark.parametrize("append", [False, True])
+def test_frozen_first_pos_matches_live_and_counts_device_settled(append):
+    """A frozen table's text-order ``first_pos`` — settled in the FM
+    launch or, past its lane budget, by host LF walks — equals the live
+    table's, also with delta tiers after an append; the counter sees
+    real patterns only."""
+    codes = codec.random_dna(2500, seed=21)
+    live = SuffixTable.from_codes(codes, is_dna=True)
+    froz = SuffixTable.from_codes(codes, is_dna=True)
+    froz.freeze()
+    text = codec.decode_dna(codes)
+    if append:
+        extra = text[100:140] + "GATTACA" * 3
+        live.append(extra)
+        froz.append(extra)
+        text += extra
+    rng = np.random.default_rng(5)
+    # 20-mers of the text occur once: 5 real rows in a bucket of 8
+    once = [text[s:s + 20] for s in rng.integers(0, 2000, 5)]
+    heavy = ["A", "C", "G", "T", "AC"]          # past the 3 x 8 lanes
+    for pats in (once, heavy, once + heavy + ["GATTACA", "CA"]):
+        a, b = live.scan(pats), froz.scan(pats)
+        assert np.array_equal(a.count, b.count)
+        assert np.array_equal(a.first_pos, b.first_pos)
+        assert [int(x) for x in b.first_pos] == [text.find(p) for p in pats]
+    froz.clear_cache()
+    froz.planner.reset_stats()
+    patt, plen = froz.planner.encode(once)
+    froz.scan_batch(patt, plen)
+    assert froz.planner.stats.device_min_settled == len(once)
+    froz.planner.reset_stats()
+    patt, plen = froz.planner.encode(heavy)
+    froz.scan_batch(patt, plen)
+    assert froz.planner.stats.device_min_settled < len(heavy)
+    assert live.planner.stats.device_min_settled == 0
